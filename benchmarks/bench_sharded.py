@@ -61,7 +61,7 @@ def _worker() -> List[Dict]:
             lambda x, wm: quantized_matmul(x, wm, W, context=ctx_pallas))
         f_xla = jax.jit(
             lambda x, wm: quantized_matmul(x, wm, W, context=ctx_xla))
-        with mesh:
+        with jax.set_mesh(mesh):
             out_p = f_pallas(x, wm)
             out_x = f_xla(x, wm)
             assert np.allclose(np.asarray(out_p), np.asarray(out_x),

@@ -3,9 +3,8 @@
 The fused single-pass kernel (kernels/fused_gemm.py) is not
 GSPMD-partitionable — XLA cannot slice through a ``pallas_call`` — so under a
 mesh every quantized GEMM used to fall back to plain dot_generals.  This
-module closes that gap: the GEMM runs under
-``jax.experimental.shard_map.shard_map`` with each shard executing the
-*unmodified* kernel on its local block.
+module closes that gap: the GEMM runs under ``jax.shard_map`` with each
+shard executing the *unmodified* kernel on its local block.
 
 Layout (capability negotiation, :func:`negotiate`):
 
@@ -39,7 +38,6 @@ from dataclasses import replace
 from typing import Optional, Tuple
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.dispatch import ExecPlan, GemmShardSpec
@@ -152,10 +150,10 @@ def shard_dense_gemm(fn, mesh: Mesh, spec: GemmShardSpec):
         raise ValueError("dense dequant GEMM requires replicated K "
                          "(fp32 bit-identity); got k_axes=%r" % (spec.k_axes,))
     ms, ns = _axis_entry(spec.m_axes), _axis_entry(spec.n_axes)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(ms, None), P(None, ns), P(ms, None), P(None, ns)),
-        out_specs=P(ms, ns), check_rep=False)
+        out_specs=P(ms, ns), check_vma=False)
 
 
 def shard_grouped_gemm(fn, mesh: Mesh, spec: GemmShardSpec,
@@ -175,9 +173,9 @@ def shard_grouped_gemm(fn, mesh: Mesh, spec: GemmShardSpec,
     in_specs = [P(es, None, None)] * 4
     if counts is not None:
         in_specs.append(P(es, None))
-    f = shard_map(
+    f = jax.shard_map(
         fn, mesh=mesh, in_specs=tuple(in_specs),
-        out_specs=P(es, None, None), check_rep=False)
+        out_specs=P(es, None, None), check_vma=False)
     if counts is None:
         return f
     return lambda qx, qw, sx, sw: f(qx, qw, sx, sw, counts)
@@ -217,9 +215,9 @@ def sharded_run_plan(a: Array, b: Array, *, plan: ExecPlan, mesh: Mesh,
             out = jax.lax.psum(out, spec.k_axes)
         return out
 
-    f = shard_map(local_fn, mesh=mesh,
-                  in_specs=(P(ms, ks), P(ks, ns)),
-                  out_specs=P(ms, ns), check_rep=False)
+    f = jax.shard_map(local_fn, mesh=mesh,
+                      in_specs=(P(ms, ks), P(ks, ns)),
+                      out_specs=P(ms, ns), check_vma=False)
     return f(a, b)
 
 

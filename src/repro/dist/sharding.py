@@ -16,8 +16,8 @@ from __future__ import annotations
 from typing import Any, Optional, Tuple
 
 import jax
-from jax.interpreters import pxla
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AbstractMesh, Mesh, NamedSharding
+from jax.sharding import PartitionSpec as P
 
 Params = Any
 
@@ -236,11 +236,10 @@ def page_pool_sharding(pool_shapes: Params, mesh: Mesh) -> Params:
     return jax.tree_util.tree_map_with_path(leaf_sharding, pool_shapes)
 
 
-def _ambient_mesh() -> Optional[Mesh]:
-    mesh = pxla.thread_resources.env.physical_mesh
-    if mesh is None or mesh.empty:
-        return None
-    return mesh
+def _ambient_mesh() -> Optional[AbstractMesh]:
+    """The mesh set by ``jax.set_mesh`` around the current trace, if any."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def constrain_batch_dim(x: jax.Array) -> jax.Array:

@@ -60,8 +60,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 Array = jax.Array
 
 # Kernel modes (digit layouts).  "auto" resolves to the paper's default for
@@ -117,8 +115,9 @@ def _fp32_dot_ok(mode: str, w: int, block_k: int) -> bool:
 
 
 def _fused_kernel(*refs, mode: str, h: int, h2: int, z: int, nk: int,
-                  kp: int, seg: int, fp32_dot: bool, combine_int32: bool,
-                  dequant: bool, grouped: bool, ragged: bool, out_dtype):
+                  kp: int, seg: int, n_seg: int, fp32_dot: bool,
+                  combine_int32: bool, dequant: bool, grouped: bool,
+                  ragged: bool, out_dtype):
     idx = 2
     a_ref, b_ref = refs[:2]
     sx_ref = sw_ref = counts_ref = None
@@ -147,13 +146,20 @@ def _fused_kernel(*refs, mode: str, h: int, h2: int, z: int, nk: int,
         # (group, m-block) — dead m-blocks skip their MXU passes, dead
         # rows inside a live block are zeroed at the combine (live rows
         # never see the mask, so they match the dense launch bit-for-bit).
+        # ``counts_ref`` is the flattened (E * n_seg,) table in SMEM; an
+        # m-block spans at most (bm - 1) // seg + 2 segments, each of whose
+        # counts is read as a scalar.
         bm = out_ref.shape[-2]
-        n_seg = counts_ref.shape[-1]
-        i = pl.program_id(1)
+        g, i = pl.program_id(0), pl.program_id(1)
         rows = i * bm + jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
         seg_ids = rows // seg
-        limit = jnp.take(counts_ref[0], jnp.clip(seg_ids, 0, n_seg - 1))
-        live = (rows - seg_ids * seg < limit) & (seg_ids < n_seg)
+        s0 = (i * bm) // seg
+        live = jnp.zeros((bm, 1), jnp.bool_)
+        for j in range(min(n_seg, (bm - 1) // seg + 2)):
+            s = jnp.minimum(s0 + j, n_seg - 1)
+            limit = counts_ref[g * n_seg + s]
+            live |= (seg_ids == s0 + j) & (rows - seg_ids * seg < limit)
+        live &= seg_ids < n_seg
 
     def _dots(pairs, accs):
         if fp32_dot:
@@ -177,10 +183,12 @@ def _fused_kernel(*refs, mode: str, h: int, h2: int, z: int, nk: int,
             acc0_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.int32)
             return
         # VPU in-register digit split + centering (ops._planes, minus the
-        # HBM plane arrays).  Digits stay in the operand carrier: their
-        # values fit it with room to spare, so the MXU products are the
-        # same exact int32 the staged plane kernels compute, without an
-        # extra narrowing cast per tile.
+        # HBM plane arrays).  The tile is widened to int32 first: the
+        # carrier stays narrow in HBM, but Mosaic has no int16 vector
+        # shift.  Digit values are unchanged, so the MXU products are the
+        # same exact int32 the staged plane kernels compute.
+        a = a.astype(jnp.int32)
+        b = b.astype(jnp.int32)
         mask = (1 << h) - 1
         a1 = jnp.right_shift(a, h)
         a0 = jnp.bitwise_and(a, mask) - z
@@ -356,8 +364,8 @@ def _fused_call(a, b, sx, sw, counts, *, grouped: bool, w: int, m: int,
 
     kernel = functools.partial(
         _fused_kernel, mode=mode, h=h, h2=h2, z=z, nk=body[2], kp=kp,
-        seg=seg or 0, fp32_dot=(mode != "mm1"
-                                and _fp32_dot_ok(mode, w, block_k)),
+        seg=seg or 0, n_seg=counts.shape[-1] if ragged else 0,
+        fp32_dot=mode != "mm1" and _fp32_dot_ok(mode, w, block_k),
         combine_int32=combine_int32, dequant=dequant, grouped=grouped,
         ragged=ragged, out_dtype=out_dtype)
     in_specs = [spec((block_m, block_k), lambda i, j, kk: (i, kk)),
@@ -369,9 +377,9 @@ def _fused_call(a, b, sx, sw, counts, *, grouped: bool, w: int, m: int,
         in_specs += [spec((block_m, 1), lambda i, j, kk: (i, 0)),
                      spec((1, block_n), lambda i, j, kk: (0, j))]
     if ragged:
-        n_seg = counts.shape[-1]
-        operands.append(counts.astype(jnp.int32))
-        in_specs.append(spec((n_seg,), lambda i, j, kk: (0,)))
+        # Whole table in SMEM, flattened so no (8, 128) tiling pads it.
+        operands.append(counts.astype(jnp.int32).reshape(-1))
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
     out = pl.pallas_call(
         kernel,
         grid=grid,
@@ -379,7 +387,7 @@ def _fused_call(a, b, sx, sw, counts, *, grouped: bool, w: int, m: int,
         out_specs=spec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct(lead + (mp, np_), out_dtype),
         scratch_shapes=_scratch_shapes(mode, block_m, block_n),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * (len(grid) - 1)
             + ("arbitrary",)),
         interpret=interpret,

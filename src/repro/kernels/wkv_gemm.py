@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 Array = jax.Array
 
 
@@ -74,7 +72,7 @@ def wkv_apply(r: Array, k: Array, v: Array, w: Array, u: Array, *,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((bh, s, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, w, u)

@@ -1,4 +1,8 @@
 import os
+# A host-device tool: pinned to the CPU, for itself and the per-cell child
+# processes it starts (they inherit this environment), so it never holds an
+# accelerator while its children start.
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: prove every (arch x shape x mesh) cell lowers,
@@ -67,7 +71,7 @@ def run_cell(arch: str, shape: str, mesh_kind: str, quant: str,
         donate = (1,)
 
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn, donate_argnums=donate).lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()

@@ -61,11 +61,13 @@ def main() -> int:
 
     from repro.configs import get_config
     from repro.core.context import ExecContext
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import lm
     from repro.obs import metrics as obs_metrics
     from repro.obs import trace as obs_trace
     from repro.serve.engine import Engine, Request
 
+    enable_compile_cache()
     # Observability is opt-in: enable before engine construction so plan
     # selection / compile-time counters during warmup are captured too.
     if args.metrics_out:

@@ -39,10 +39,12 @@ def main() -> int:
                         format="%(asctime)s %(name)s %(message)s")
     from repro.configs import get_config
     from repro.data.pipeline import DataConfig
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_mesh
     from repro.train import optim
     from repro.train.loop import TrainConfig, run_training
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke, quant=args.quant)
     shape = tuple(int(x) for x in args.mesh.split("x"))
     mesh = make_mesh(shape)

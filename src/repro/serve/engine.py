@@ -189,7 +189,8 @@ class Engine:
     # -- infrastructure -----------------------------------------------------
 
     def _mesh_ctx(self):
-        return self.mesh if self.mesh is not None else contextlib.nullcontext()
+        return (jax.set_mesh(self.mesh) if self.mesh is not None
+                else contextlib.nullcontext())
 
     def _now(self) -> float:
         return time.monotonic() - self._clock0

@@ -83,7 +83,7 @@ def run_training(cfg: ModelConfig, mesh: Mesh, tc: TrainConfig,
         frontend_tokens=cfg.frontend_tokens, encdec=cfg.is_encdec,
         seed=tc.seed)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         params_abs = steps_mod.abstract_params(cfg, mesh)
         param_sh = jax.tree.map(lambda a: a.sharding, params_abs)
         key = jax.random.PRNGKey(tc.seed)

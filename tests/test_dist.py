@@ -7,7 +7,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.dist import sharding as shard
 from repro.dist.collectives import (
@@ -66,7 +66,7 @@ def test_ring_ag_matmul_matches_dense():
     w = jnp.array(rng.standard_normal((16, 4)), jnp.float32)
     f = shard_map(lambda xs, w: ring_ag_matmul(xs, w, "model"),
                   mesh=mesh, in_specs=(P("model", None), P(None, None)),
-                  out_specs=P(None, None), check_rep=False)
+                  out_specs=P(None, None), check_vma=False)
     np.testing.assert_allclose(np.asarray(f(x, w)), np.asarray(x @ w),
                                rtol=1e-5, atol=1e-5)
 
@@ -78,7 +78,7 @@ def test_ring_ag_matmul_int_path():
     w = jnp.array(rng.standard_normal((32, 8)), jnp.float32)
     f = shard_map(lambda xs, w: ring_ag_matmul(xs, w, "model", w_bits=8),
                   mesh=mesh, in_specs=(P("model", None), P(None, None)),
-                  out_specs=P(None, None), check_rep=False)
+                  out_specs=P(None, None), check_vma=False)
     ref = np.asarray(x @ w)
     got = np.asarray(f(x, w))
     # int8-quantized operands: first-order quantization noise
@@ -98,7 +98,7 @@ def test_splitk_decode_attention_matches_softmax():
                   mesh=mesh,
                   in_specs=(P(), P(None, "model"), P(None, "model"),
                             P(None, "model")),
-                  out_specs=P(), check_rep=False)
+                  out_specs=P(), check_vma=False)
     out = f(q, k, v, valid)
     scores = jnp.einsum("bhd,bshd->bhs", q, k) * (D ** -0.5)
     scores = jnp.where(valid[:, None, :], scores, -1e30)

@@ -18,7 +18,7 @@ sys.path.insert(0, sys.argv[1])
 import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.configs import get_config
 from repro.dist.collectives import (
@@ -68,7 +68,7 @@ w = jax.random.normal(jax.random.PRNGKey(2), (16, 8))
 x = jax.random.normal(jax.random.PRNGKey(3), (8, 16))   # rows sharded by 4
 ring = shard_map(lambda xs, w: ring_ag_matmul(xs, w, "model"),
                  mesh=mesh, in_specs=(P("model", None), P(None, None)),
-                 out_specs=P(None, None), check_rep=False)
+                 out_specs=P(None, None), check_vma=False)
 out = ring(x, w)
 np.testing.assert_allclose(np.asarray(out), np.asarray(x @ w), rtol=1e-4)
 
@@ -81,7 +81,7 @@ valid = jnp.ones((B, S), bool)
 fk = shard_map(lambda q, k, v, m: splitk_decode_attention(q, k, v, m, "model"),
                mesh=mesh,
                in_specs=(P(), P(None, "model"), P(None, "model"), P(None, "model")),
-               out_specs=P(), check_rep=False)
+               out_specs=P(), check_vma=False)
 out = fk(q, k, v, valid)
 scores = jnp.einsum("bhd,bshd->bhs", q, k) * (D ** -0.5)
 ref = jnp.einsum("bhs,bshd->bhd", jax.nn.softmax(scores, -1), v)
@@ -93,7 +93,7 @@ cell = ShapeCell("t", 64, 8, "train")
 ocfg = optim.AdamWConfig()
 specs_in = input_specs(cfg, cell, mesh, ocfg)
 step_fn = jax.jit(make_train_step(cfg, ocfg), donate_argnums=(0, 1))
-with mesh:
+with jax.set_mesh(mesh):
     params = jax.jit(lambda k: lm.init_params(k, cfg),
                      out_shardings=jax.tree.map(lambda a: a.sharding,
                                                 specs_in["params"]))(
@@ -112,7 +112,7 @@ with mesh:
 # ---- decode on sharded cache -------------------------------------------------
 cache = lm.init_cache(cfg, 8, 64)
 cs = cache_sharding(jax.eval_shape(lambda: lm.init_cache(cfg, 8, 64)), mesh, batch=8)
-with mesh:
+with jax.set_mesh(mesh):
     cache = jax.tree.map(lambda c, s: jax.device_put(c, s), cache, cs)
     logits, cache = jax.jit(
         lambda p, c, tok, t: lm.decode_step(p, cfg, tok, c, t))(

@@ -63,7 +63,7 @@ wm = jnp.asarray(rng.standard_normal((K, N)), jnp.float32)
 unsharded = quantized_matmul(x, wm, 12, context=ExecContext(backend="pallas"))
 xs = jax.device_put(x, NamedSharding(mesh, P("data", None)))
 ws = jax.device_put(wm, NamedSharding(mesh, P(None, "model")))
-with mesh:
+with jax.set_mesh(mesh):
     sharded = quantized_matmul(xs, ws, 12,
                                context=ExecContext(backend="pallas",
                                                    mesh=mesh))
@@ -74,7 +74,7 @@ assert np.array_equal(np.asarray(sharded), np.asarray(unsharded)), \
 a8 = jnp.asarray(rng.integers(-120, 120, (M, K)), jnp.int32)
 b8 = jnp.asarray(rng.integers(-120, 120, (K, N)), jnp.int32)
 plan8 = select_plan((M, K, N), 8, backend="pallas")
-with mesh:
+with jax.set_mesh(mesh):
     out8 = sg.sharded_run_plan(a8, b8, plan=plan8, mesh=mesh)
 oracle = ref_int_gemm_i64(np.asarray(a8), np.asarray(b8))
 assert np.array_equal(np.asarray(out8).astype(np.int64), oracle), \
@@ -83,7 +83,7 @@ assert np.array_equal(np.asarray(out8).astype(np.int64), oracle), \
 # ---- K-sharded exact-int split (psum of int32 partials) -------------------
 kspec = GemmShardSpec(m_axes=("data",), k_axes=("model",))
 from dataclasses import replace
-with mesh:
+with jax.set_mesh(mesh):
     outk = sg.sharded_run_plan(a8, b8, plan=replace(plan8, shard=kspec),
                                mesh=mesh)
 assert np.array_equal(np.asarray(outk).astype(np.int64), oracle), \
@@ -91,7 +91,7 @@ assert np.array_equal(np.asarray(outk).astype(np.int64), oracle), \
 plan12 = select_plan((M, K, N), 12, backend="pallas")
 if not plan12.is_exact_int:
     try:
-        with mesh:
+        with jax.set_mesh(mesh):
             sg.sharded_run_plan(a8, b8, plan=replace(plan12, shard=kspec),
                                 mesh=mesh)
         raise AssertionError("fp32-combine plan accepted K-sharding")
@@ -104,7 +104,7 @@ xb = jnp.asarray(rng.standard_normal((E, C, 64)), jnp.float32)
 wb = jnp.asarray(rng.standard_normal((E, 64, 96)), jnp.float32)
 unsharded_b = quantized_matmul_batched(xb, wb, 12,
                                        context=ExecContext(backend="pallas"))
-with mesh:
+with jax.set_mesh(mesh):
     sharded_b = quantized_matmul_batched(
         xb, wb, 12, context=ExecContext(backend="pallas", mesh=mesh))
 assert np.array_equal(np.asarray(sharded_b), np.asarray(unsharded_b)), \
@@ -118,7 +118,7 @@ logging.getLogger("repro.dist").addHandler(handler)
 logging.getLogger("repro.dist").setLevel(logging.INFO)
 x_odd = jnp.asarray(rng.standard_normal((M, K)), jnp.float32)
 w_odd = jnp.asarray(rng.standard_normal((K, 1025)), jnp.float32)
-with mesh:
+with jax.set_mesh(mesh):
     out_odd = quantized_matmul(
         x_odd, w_odd, 12, context=ExecContext(backend="pallas", mesh=mesh))
 ref_odd = quantized_matmul(x_odd, w_odd, 12)   # xla, default context
@@ -127,7 +127,7 @@ np.testing.assert_allclose(np.asarray(out_odd), np.asarray(ref_odd),
                            rtol=1e-5, atol=1e-5)
 # force a total fallback with an indivisible M too:
 x_np = jnp.asarray(rng.standard_normal((33, K)), jnp.float32)
-with mesh:
+with jax.set_mesh(mesh):
     out_np = quantized_matmul(
         x_np, w_odd, 12, context=ExecContext(backend="pallas", mesh=mesh))
 assert any("falls back to XLA" in m for m in records), records
