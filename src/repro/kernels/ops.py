@@ -43,7 +43,6 @@ import jax.numpy as jnp
 
 from repro.core.dispatch import ExecPlan, Mode, select_plan
 from repro.core.strassen import STRASSEN_VARIANTS, strassen_matmul
-from repro.obs import trace as obs_trace
 from repro.core.kmm import kmm_n, mm_n, max_exact_k
 from repro.kernels.ffip import ffip_gemm_literal
 from repro.kernels.fused_gemm import fused_gemm
@@ -150,24 +149,6 @@ def run_plan(a: Array, b: Array, *, plan: ExecPlan,
     with M/N axes from ``plan.shard`` (negotiated when unset).  XLA-backend
     plans ignore the mesh (plain dot_generals partition via GSPMD).
     """
-    if not obs_trace.enabled():
-        return _run_plan_impl(a, b, plan=plan, interpret=interpret,
-                              use_ref_kernels=use_ref_kernels, mesh=mesh,
-                              context=context)
-    # Host-side span: inside a jit this fires once per TRACE (a build-time
-    # record, never a per-call device sync); eager calls time the dispatch.
-    with obs_trace.span("run_plan", variant=plan.variant, w=plan.w,
-                        backend=plan.backend, depth=plan.depth,
-                        shape=f"{a.shape[0]}x{a.shape[1]}x{b.shape[-1]}"):
-        return _run_plan_impl(a, b, plan=plan, interpret=interpret,
-                              use_ref_kernels=use_ref_kernels, mesh=mesh,
-                              context=context)
-
-
-def _run_plan_impl(a: Array, b: Array, *, plan: ExecPlan,
-                   interpret: Optional[bool] = None,
-                   use_ref_kernels: bool = False,
-                   mesh=None, context=None) -> Array:
     if mesh is None and context is not None:
         mesh = context.mesh
     if mesh is not None and plan.backend == "pallas" \
@@ -184,8 +165,8 @@ def _run_plan_impl(a: Array, b: Array, *, plan: ExecPlan,
         # the full stack — fused Pallas kernels, interpret mode and the
         # ref-kernel oracle mirror included.
         def run_sub(x, y, sub_plan):
-            return _run_plan_impl(x, y, plan=sub_plan, interpret=interpret,
-                                  use_ref_kernels=use_ref_kernels)
+            return run_plan(x, y, plan=sub_plan, interpret=interpret,
+                            use_ref_kernels=use_ref_kernels)
         return strassen_matmul(a, b, plan=plan, run_sub=run_sub)
     if plan.variant == "xla_ref":
         return ref_int_gemm(a, b)

@@ -1,11 +1,12 @@
 """repro.obs — unified observability: metrics, tracing, traffic (DESIGN.md §14).
 
-Three host-side subsystems, all zero-overhead when disabled (the default):
+Three host-side subsystems, off by default:
 
   * :mod:`repro.obs.metrics` — process-global counter/gauge/histogram
     registry (JSON snapshot + Prometheus text export);
-  * :mod:`repro.obs.trace`   — structured span tracer exporting
-    Chrome-trace/Perfetto JSON;
+  * :mod:`repro.obs.trace`   — structured span tracer: every span is a
+    ``jax.profiler.TraceAnnotation`` (kept only while a profiler session
+    runs), and enabled it also buffers Chrome-trace/Perfetto JSON;
   * :mod:`repro.obs.traffic` — measured memory-traffic accounting
     (compiler bytes-accessed vs the analytic plane-traffic model).
 

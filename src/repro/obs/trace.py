@@ -1,12 +1,20 @@
-"""Host-side structured span tracer with Chrome-trace / Perfetto export.
+"""Structured span tracer on the profiler's clock, with Chrome-trace /
+Perfetto export.
 
-Spans are plain host-Python timing records around host-side control flow:
-per-request lifetimes and per-engine-step phases in the serve engine, and
-per-plan spans around ``run_plan``.  Nothing here touches jax — opening a
-span inside a jitted function's *trace* records the (one-time) trace cost,
-never a per-call device sync, and with tracing disabled (the default) a
-``span(...)`` call returns a shared null singleton: no allocation, no
-contextvar write, no clock read.  Enabling tracing therefore cannot change
+Spans are host-Python timing records around host-side control flow:
+per-request lifetimes and per-engine-step phases in the serve engine.
+Every ``span(name, **attrs)`` is also a ``jax.profiler.TraceAnnotation`` of
+the same name, whether or not tracing is enabled: the profiler keeps it
+only while a profiler session runs (``jax.profiler.trace``), and then it
+sits on the same timeline as the device planes' ``XLA Ops``, with
+``attrs`` as the event's stats.  With no session an annotation costs about
+a microsecond and records nothing.  jax is imported on the first span, so
+this module stays light to import.
+
+``enable()`` turns on the in-memory Chrome buffer besides: with it off (the
+default) a span writes no buffer event and reads no clock of its own.
+Opening a span inside a jitted function's *trace* records the (one-time)
+trace cost, never a per-call device sync, so enabling tracing cannot change
 any computed value (pinned by the serve token-identity test).
 
 Export is the Chrome trace-event JSON format (``chrome://tracing`` /
@@ -59,35 +67,38 @@ def _now_us() -> float:
     return (time.perf_counter_ns() - _EPOCH_NS) / 1e3
 
 
-class _NullSpan:
-    """Shared no-op span: the entire disabled-path cost of ``with span(...)``
-    is one flag test plus entering/exiting this singleton."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def set(self, **attrs) -> None:
-        pass
+_Annotation = None
 
 
-_NULL_SPAN = _NullSpan()
+def _annotation(name: str, attrs: Dict[str, object]):
+    """A profiler annotation (``TraceAnnotation`` with a no-op ``set``);
+    jax is imported on the first call."""
+    global _Annotation
+    if _Annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        class _ProfilerSpan(TraceAnnotation):
+            """The whole span while the Chrome buffer is off."""
+
+            def set(self, **attrs) -> None:
+                pass
+
+        _Annotation = _ProfilerSpan
+    return _Annotation(name, **attrs)
 
 
 class _Span:
-    __slots__ = ("name", "args", "_t0", "_token")
+    __slots__ = ("name", "args", "_t0", "_token", "_ann")
 
     def __init__(self, name: str, args: Dict[str, object]):
         self.name = name
         self.args = args
         self._t0 = 0.0
         self._token = None
+        self._ann = _annotation(name, args)
 
     def __enter__(self):
+        self._ann.__enter__()
         path = _span_path.get()
         self.args["depth"] = len(path)
         if path:
@@ -111,20 +122,23 @@ class _Span:
         }
         with _lock:
             _events.append(event)
+        self._ann.__exit__(exc_type, exc, tb)
         return False
 
     def set(self, **attrs) -> None:
-        """Attach attributes discovered mid-span (e.g. chosen lane width)."""
+        """Attach attributes discovered mid-span (e.g. chosen lane width)
+        to the Chrome event; the profiler keeps those given at open."""
         self.args.update(attrs)
 
 
 def span(name: str, **attrs):
-    """Context manager timing a host-side region.
+    """Context manager timing a host-side region, on the profiler's
+    timeline always and in the Chrome buffer when enabled.
 
-    ``with trace.span("decode_step", step=i) as sp: ... sp.set(lanes=4)``
+    ``with trace.span("serve.prefill", rid=3) as sp: ... sp.set(lanes=4)``
     """
     if not _enabled:
-        return _NULL_SPAN
+        return _annotation(name, attrs)
     return _Span(name, dict(attrs))
 
 

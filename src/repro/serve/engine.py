@@ -76,6 +76,12 @@ _TTFT = obs_metrics.histogram(
     "repro_serve_ttft_seconds", "arrival to first token, per request")
 _DECODE_STEP = obs_metrics.histogram(
     "repro_serve_decode_step_seconds", "wall time of one bucketed decode step")
+# One observation per engine step for each phase that ran (admit, prefill,
+# decode_dispatch, decode_wait, finish): the top buckets catch host stalls
+# that a profiled slice of the run would miss.
+_STEP_PHASE = obs_metrics.histogram(
+    "repro_serve_step_phase_seconds", "wall time of one phase of an engine step",
+    labels=("phase",))
 _OCCUPANCY = obs_metrics.gauge(
     "repro_serve_occupancy", "live slots / total slots at the last decode step")
 _FINISHED = obs_metrics.counter(
@@ -182,7 +188,7 @@ class Engine:
             self.prefix = PrefixCache(self.pool, align)
 
         self._next_rid = 0
-        self._clock0 = time.monotonic()
+        self._clock0 = time.perf_counter()
         self._stats = ServeStats()
         self._admitted_done: List[Request] = []
 
@@ -193,7 +199,9 @@ class Engine:
                 else contextlib.nullcontext())
 
     def _now(self) -> float:
-        return time.monotonic() - self._clock0
+        # perf_counter: the clock of repro.obs.trace, so every host record
+        # of a run shares one clock
+        return time.perf_counter() - self._clock0
 
     def _bucket(self, n: int, buckets: Sequence[int]) -> int:
         for b in buckets:
@@ -321,33 +329,37 @@ class Engine:
         toks = np.zeros((1, width), np.int32)
         toks[0, :take] = req.prompt[ps.off:ps.off + take]   # right-pad
         last = np.array([take - 1], np.int32)
-        stats = self._stats
-        with self._mesh_ctx():
-            t0 = time.monotonic()
-            with obs_trace.span("prefill_chunk", slot=idx,
-                                rid=req.stats.rid, off=ps.off, width=width):
-                logits = self.executor.prefill(idx, toks, ps.off, last)
-                if ps.off + take < plen:
-                    jax.block_until_ready(logits)
-            stats.prefill_s += time.monotonic() - t0
+        tok = None
+        with self._mesh_ctx(), obs_trace.span(
+                "serve.prefill", rid=req.stats.rid, slot=idx, off=ps.off,
+                width=width):
+            t0 = time.perf_counter()
+            logits = self.executor.prefill(idx, toks, ps.off, last)
             ps.off += take
+            if ps.off < plen:
+                jax.block_until_ready(logits)
+            else:
+                # prompt complete: sample the first token from the last
+                # chunk's last-real-position logits
+                tok = int(np.asarray(self.executor.sample(
+                    self._key, logits,
+                    np.asarray([req.temperature], np.float32),
+                    np.asarray([req.stats.rid], np.int32),
+                    np.asarray([0], np.int32)))[0])
+            # synced: the clock stops after the device finished the chunk
+            # (after the first token's readback on the last one)
+            dt = time.perf_counter() - t0
+            req.stats.prefill_s += dt
             if self.prefix is not None and ps.off == ps.snap_at \
                     and ps.snap_at > 0:
                 self.prefix.store(idx, req.prompt, ps.snap_at)
-            if ps.off < plen:
-                return None
-            # prompt complete: sample the first token from the last chunk's
-            # last-real-position logits
-            tok = int(np.asarray(self.executor.sample(
-                self._key, logits,
-                np.asarray([req.temperature], np.float32),
-                np.asarray([req.stats.rid], np.int32),
-                np.asarray([0], np.int32)))[0])
+        if tok is None:
+            return None
         self.scheduler.prefill_done(idx, tok)
         req.generated.append(tok)
         req.stats.first_token_s = self._now()
         _TTFT.observe(req.stats.ttft_s)
-        stats.generated_tokens += 1
+        self._stats.generated_tokens += 1
         reason = self._check_done(slot, tok)
         if reason is not None:      # e.g. max_new_tokens=1 or instant EOS
             self._finish(idx, reason)
@@ -359,17 +371,17 @@ class Engine:
         every admitted prompt (admission-time prefill, the dense-engine
         behavior); with chunking on, advance one prefilling slot by one
         chunk so prompts interleave with decode steps."""
-        if self.prefill_chunk is None:
-            for idx in self.scheduler.prefilling():
-                req = self._run_prefill_chunk(idx)
-                if req is not None:
-                    self._admitted_done.append(req)
-        else:
-            idxs = self.scheduler.prefilling()
-            if idxs:
-                req = self._run_prefill_chunk(idxs[0])
-                if req is not None:
-                    self._admitted_done.append(req)
+        idxs = self.scheduler.prefilling()
+        if not idxs:
+            return
+        if self.prefill_chunk is not None:
+            idxs = idxs[:1]
+        t0 = time.perf_counter()
+        for idx in idxs:
+            req = self._run_prefill_chunk(idx)
+            if req is not None:
+                self._admitted_done.append(req)
+        _STEP_PHASE.observe(time.perf_counter() - t0, "prefill")
 
     # -- decode -------------------------------------------------------------
 
@@ -392,33 +404,41 @@ class Engine:
         steps = np.array([slots[j].n_tokens if j is not None else 0
                           for j in lanes], np.int32)
         stats = self._stats
-        t0 = time.monotonic()
         with self._mesh_ctx():
-            with obs_trace.span("decode_step", n_live=n_live,
+            t0 = time.perf_counter()
+            with obs_trace.span("serve.decode.dispatch", n_live=n_live,
                                 width=len(lanes)):
                 logits = self.executor.decode(lanes, toks, pos)
-                nxt = np.asarray(self.executor.sample(
-                    self._key, logits, temps, rids, steps))
-        dt = time.monotonic() - t0
+                sampled = self.executor.sample(
+                    self._key, logits, temps, rids, steps)
+            t1 = time.perf_counter()
+            with obs_trace.span("serve.decode.wait"):
+                nxt = np.asarray(sampled)
+            t2 = time.perf_counter()
+        _STEP_PHASE.observe(t1 - t0, "decode_dispatch")
+        _STEP_PHASE.observe(t2 - t1, "decode_wait")
+        dt = t2 - t0
         stats.decode_s += dt
         stats.decode_steps += 1
         stats.occupancy_sum += n_live / self.batch
         _DECODE_STEP.observe(dt)
         _OCCUPANCY.set(n_live / self.batch)
         finished: List[Request] = []
-        for lane, idx in enumerate(lanes[:n_live]):     # live lanes first
-            slot = slots[idx]
-            tok = int(nxt[lane])
-            slot.pos += 1
-            slot.last_tok = tok
-            slot.n_tokens += 1
-            slot.req.generated.append(tok)
-            stats.generated_tokens += 1
-            reason = self._check_done(slot, tok)
-            if reason is not None:
-                req = slot.req
-                self._finish(idx, reason)
-                finished.append(req)
+        with obs_trace.span("serve.finish"):
+            for lane, idx in enumerate(lanes[:n_live]):     # live lanes first
+                slot = slots[idx]
+                tok = int(nxt[lane])
+                slot.pos += 1
+                slot.last_tok = tok
+                slot.n_tokens += 1
+                slot.req.generated.append(tok)
+                stats.generated_tokens += 1
+                reason = self._check_done(slot, tok)
+                if reason is not None:
+                    req = slot.req
+                    self._finish(idx, reason)
+                    finished.append(req)
+        _STEP_PHASE.observe(time.perf_counter() - t2, "finish")
         return finished
 
     # -- step / driver ------------------------------------------------------
@@ -428,15 +448,17 @@ class Engine:
         step.  Returns the requests that finished during this step —
         including those that finished at admission (first prefill token hit
         EOS or a 1-token budget)."""
-        t0 = time.monotonic()
-        with obs_trace.span("engine_step"):
-            for idx, req in self.scheduler.admit(self._now()):
-                self._init_slot(idx, req)
+        t0 = time.perf_counter()
+        with obs_trace.span("serve.step"):
+            with obs_trace.span("serve.admit"):
+                for idx, req in self.scheduler.admit(self._now()):
+                    self._init_slot(idx, req)
+            _STEP_PHASE.observe(time.perf_counter() - t0, "admit")
             self._prefill_step()
             finished = self._admitted_done
             self._admitted_done = []
             finished += self._decode_step()
-        self._stats.busy_s += time.monotonic() - t0
+        self._stats.busy_s += time.perf_counter() - t0
         return finished
 
     def generate(self, requests: List[Request],
@@ -448,7 +470,7 @@ class Engine:
         trace: a request is only admitted once its arrival time has passed
         (TTFT then includes queueing delay)."""
         self._stats = ServeStats()
-        self._clock0 = time.monotonic()
+        self._clock0 = time.perf_counter()
         if arrival_s is None:
             for r in requests:
                 self.submit(r)

@@ -57,6 +57,9 @@ class Executor:
         self.mesh = mesh
         pps, page, smax = pool.pages_per_slot, pool.page_size, pool.max_seq
 
+        # named scopes mark the cache layer's copies in the HLO metadata
+        # (op names and instruction names are unchanged)
+        @jax.named_scope("kv_gather")
         def gather(pools, prows, srows):
             def leaf(path, pool_arr):
                 if is_paged_leaf(path):
@@ -68,6 +71,7 @@ class Executor:
                 return pool_arr[:, srows]
             return jax.tree_util.tree_map_with_path(leaf, pools)
 
+        @jax.named_scope("kv_scatter")
         def scatter(pools, lanes, prows, srows):
             def leaf(path, pool_arr, lane):
                 if is_paged_leaf(path):

@@ -75,6 +75,10 @@ class RequestStats:
     rid: int
     prompt_len: int
     arrival_s: float
+    admit_s: float = 0.0           # when the scheduler gave it a slot
+    # device-synced prefill seconds over the request's chunks; the clock of
+    # the last chunk stops after the first token's readback
+    prefill_s: float = 0.0
     first_token_s: float = 0.0
     finish_s: float = 0.0
     n_tokens: int = 0
@@ -91,13 +95,17 @@ class RequestStats:
 
 @dataclass
 class ServeStats:
-    prefill_s: float = 0.0
     decode_s: float = 0.0
     busy_s: float = 0.0            # wall-clock span of engine activity
     decode_steps: int = 0          # batched engine steps
     generated_tokens: int = 0      # actual tokens produced across requests
     occupancy_sum: float = 0.0     # sum over decode steps of live/slots
     requests: List[RequestStats] = field(default_factory=list)
+
+    @property
+    def prefill_s(self) -> float:
+        """Synced prefill seconds: the sum of the finished requests'."""
+        return sum(r.prefill_s for r in self.requests)
 
     @property
     def tokens_per_s(self) -> float:
@@ -192,6 +200,7 @@ class Scheduler:
             if free is None:
                 break
             req = self._pending.popleft()
+            req.stats.admit_s = now
             slot = self.slots[free]
             slot.req = req
             slot.pos = 0

@@ -6,8 +6,9 @@ Pins the three contracts DESIGN.md §14 promises:
     registration, deterministic snapshots, thread-safety under concurrent
     writers, Prometheus text shape, and the disabled path recording nothing;
   * span tracer — contextvar nesting (depth/parent), Chrome trace-event
-    schema of the export, async begin/end pairing, and the shared null-span
-    singleton on the disabled path;
+    schema of the export, async begin/end pairing, the disabled path
+    writing no buffer event and reading no clock of its own, and the serve
+    engine's spans on the profiler's timeline;
   * traffic harness — cost_analysis bytes/flops validated against a
     hand-computed plain matmul, and the measured-vs-analytic rows/checks on
     a tiny shape;
@@ -156,14 +157,21 @@ def test_counter_thread_safety(obs_on):
 # ---------------------------------------------------------------------------
 
 
-def test_disabled_span_is_shared_null():
+def test_disabled_span_is_shared_null(monkeypatch):
+    """Disabled, a span is only a profiler annotation: it shares the null
+    Chrome path (no buffer write, no read of the tracer's clock)."""
     assert not trace.enabled()
-    s1 = trace.span("a", k=1)
-    s2 = trace.span("b")
-    assert s1 is s2                   # singleton: no per-call allocation
-    with s1 as sp:
+
+    def no_clock():
+        raise AssertionError("the disabled path read the tracer's clock")
+    monkeypatch.setattr(trace, "_now_us", no_clock)
+    with trace.span("a", k=1) as sp:
         sp.set(x=2)                   # no-op, no error
+    with trace.span("b"):
+        pass
     trace.instant("nothing")
+    trace.begin_async("request", 1)
+    trace.end_async("request", 1)
     assert trace.events() == []
 
 
@@ -301,12 +309,17 @@ def tiny():
     return cfg, params
 
 
-def _generate(cfg, params):
-    from repro.serve.engine import Engine, Request
+def _requests(cfg, lengths=((3, 6, 0.0), (9, 3, 0.7), (5, 5, 0.0))):
+    from repro.serve.engine import Request
     rng = np.random.default_rng(0)
-    reqs = [Request(prompt=list(rng.integers(1, cfg.vocab_size, size=n)),
+    return [Request(prompt=list(rng.integers(1, cfg.vocab_size, size=n)),
                     max_new_tokens=m, temperature=t)
-            for n, m, t in ((3, 6, 0.0), (9, 3, 0.7), (5, 5, 0.0))]
+            for n, m, t in lengths]
+
+
+def _generate(cfg, params):
+    from repro.serve.engine import Engine
+    reqs = _requests(cfg)
     eng = Engine(cfg, params, max_seq=32, batch_size=2, rng_seed=3)
     eng.generate(reqs)
     return [r.generated for r in reqs], eng
@@ -337,7 +350,9 @@ def test_serve_tokens_identical_with_obs_enabled(tiny):
         assert retr.value("decode") == eng.n_traces()["decode"]
 
         names = {e["name"] for e in trace.events()}
-        assert {"engine_step", "decode_step", "request"} <= names
+        assert {"serve.step", "serve.admit", "serve.prefill",
+                "serve.decode.dispatch", "serve.decode.wait", "serve.finish",
+                "request"} <= names
         reqs = [e for e in trace.events() if e["name"] == "request"]
         assert sorted(e["ph"] for e in reqs) == ["b"] * 3 + ["e"] * 3
     finally:
@@ -349,3 +364,69 @@ def test_serve_tokens_identical_with_obs_enabled(tiny):
     # and back off: still identical (no sticky state)
     again, _ = _generate(cfg, params)
     assert again == baseline
+
+
+def test_serve_spans_on_the_profiler_timeline(tiny, tmp_path):
+    """With a profiler session around generate, the engine's spans land on
+    a host plane of the trace, the phases nested inside ``serve.step``."""
+    from jax.profiler import ProfileData
+    from repro.serve.engine import Engine
+
+    cfg, params = tiny
+    eng = Engine(cfg, params, max_seq=32, batch_size=2, rng_seed=3)
+    eng.generate(_requests(cfg))          # compile outside the session
+    with jax.profiler.trace(str(tmp_path)):
+        eng.generate(_requests(cfg))
+    files = sorted(tmp_path.glob("**/*.xplane.pb"))
+    assert files
+    spans = {}
+    for plane in ProfileData.from_file(str(files[-1])).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("serve."):
+                    spans.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns))
+    steps = spans.get("serve.step", [])
+    assert steps
+    for name in ("serve.admit", "serve.prefill", "serve.decode.dispatch",
+                 "serve.decode.wait"):
+        assert spans.get(name), name
+        assert all(any(a <= x and y <= b for a, b in steps)
+                   for x, y in spans[name]), name
+
+
+@pytest.mark.parametrize("chunk", [None, 8], ids=["whole", "chunked"])
+def test_request_records_are_ordered_and_prefill_is_synced(tiny, chunk):
+    from repro.serve.engine import Engine
+
+    cfg, params = tiny
+    reqs = _requests(cfg, ((19, 4, 0.0), (9, 3, 0.7), (5, 5, 0.0)))
+    eng = Engine(cfg, params, max_seq=32, batch_size=2, rng_seed=3,
+                 prefill_chunk=chunk)
+    stats = eng.generate(reqs)
+    assert len(stats.requests) == 3
+    for r in reqs:
+        rs = r.stats
+        assert rs.arrival_s <= rs.admit_s <= rs.first_token_s
+        assert rs.prefill_s > 0
+        assert rs.prefill_s <= rs.first_token_s - rs.admit_s
+    assert stats.prefill_s == pytest.approx(
+        sum(rs.prefill_s for rs in stats.requests), rel=1e-12)
+
+
+def test_step_phases_count_every_decode_step(tiny, obs_on):
+    from repro.serve.engine import Engine
+
+    cfg, params = tiny
+    eng = Engine(cfg, params, max_seq=32, batch_size=2, rng_seed=3)
+    stats = eng.generate(_requests(cfg))
+    phases = metrics.get("repro_serve_step_phase_seconds")
+    decode = metrics.get("repro_serve_decode_step_seconds")
+    assert decode.count() == stats.decode_steps > 0
+    assert phases.count("decode_wait") == decode.count()
+    assert phases.count("decode_dispatch") == decode.count()
+    assert phases.count("finish") == decode.count()
+    assert phases.count("admit") >= decode.count()
+    assert phases.count("prefill") > 0
