@@ -15,7 +15,9 @@ that
   * performs the digit split(s) and low-digit centering on the VPU
     in-register, per (bm, bk)/(bk, bn) tile (the Fig. 8 X-adder vector);
   * runs the mode's MXU passes against persistent int32 VMEM accumulators
-    across the K grid:
+    across the K grid, as int8 x int8 -> int32 passes on the chip wherever
+    every digit of the mode fits int8 (:func:`dot_path`, :func:`digit_range`;
+    the tiles of that path come from :func:`int8_tiles`):
 
       - ``mm1``  (w <= m):        1 pass, no split;
       - ``kmm2`` (m < w <= 2m-2): 3 passes (C1, Cs, C0);
@@ -53,14 +55,24 @@ row skip their MXU passes entirely.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from dataclasses import replace
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.obs import metrics as obs_metrics
+
 Array = jax.Array
+
+# Which digit-dot path each fused launch took (trace-time: one hit per
+# trace of the kernel, like repro_quant_gemm_routes_total).
+_DIGIT_DOTS = obs_metrics.counter(
+    "repro_fused_digit_dot_total",
+    "fused-kernel launches traced, by mode and digit-dot path",
+    labels=("mode", "path"))
 
 # Kernel modes (digit layouts).  "auto" resolves to the paper's default for
 # the width: mm1 (w <= m) or kmm2 (above).  mm2 and kmm4 are explicit
@@ -108,14 +120,140 @@ def _fp32_dot_ok(mode: str, w: int, block_k: int) -> bool:
     with magnitude <= 2^leaf_mag_bits, so every K-dot partial sum over a
     block_k-deep tile is an integer of magnitude <= block_k * 2^(2*bits).
     While that stays <= 2^24 every value is exactly representable in fp32:
-    the MXU-native fp32 pass computes the same integers the integer path
-    does, bit for bit, and the int32 cast is lossless."""
+    the fp32 pass computes the same integers the integer path does, bit for
+    bit, and the int32 cast is lossless."""
     bits = leaf_mag_bits(mode, w)
     return block_k <= (1 << max(24 - 2 * bits, 0))
 
 
+def _span(lo: int, hi: int, s: int) -> Tuple[int, int]:
+    """Exact [min, max] of ``(v >> s) + (v & (2^s - 1))`` over every integer
+    v in [lo, hi] (the nested pre-adder of a plain split at s).  Within one
+    s-aligned row the sum rises with v, so the extremes sit at lo, hi, the
+    first value of the row above lo's, and the last value of the row below
+    hi's."""
+    mask = (1 << s) - 1
+    cands = [lo, hi]
+    first = ((lo >> s) + 1) << s
+    last = ((hi >> s) << s) - 1
+    cands += [v for v in (first, last) if lo <= v <= hi]
+    vals = [(v >> s) + (v & mask) for v in cands]
+    return min(vals), max(vals)
+
+
+@functools.lru_cache(maxsize=None)
+def digit_range(mode: str, w: int) -> Tuple[int, int]:
+    """Exact [min, max] over every operand of every MXU pass of a split
+    mode, taken over all signed ``w``-bit inputs.
+
+    With ``h = ceil(w/2)`` and ``z = 2^(h-1)``, a w-bit value splits into a
+    signed high digit ``a >> h`` in [-2^(w-1-h), 2^(w-1-h) - 1] and a
+    centered low digit ``(a & (2^h - 1)) - z`` in [-z, z - 1]; the two are
+    independent, so the Fig. 8 pre-adder ``a1 + (a0 - z)`` covers the sum
+    of the intervals.  kmm4 re-splits each of those three branches plainly
+    at ``h2 = ceil((h+1)/2)`` and pre-adds the halves again (:func:`_span`).
+    """
+    h = -(-w // 2)
+    z = 1 << (h - 1)
+    hi_d = (-(1 << max(w - 1 - h, 0)), (1 << max(w - 1 - h, 0)) - 1)
+    lo_d = (-z, z - 1)
+    pre = (hi_d[0] + lo_d[0], hi_d[1] + lo_d[1])
+    if mode == "mm2":
+        ranges = [hi_d, lo_d]
+    elif mode == "kmm2":
+        ranges = [hi_d, lo_d, pre]
+    elif mode == "kmm4":
+        h2 = -(-(h + 1) // 2)
+        ranges = []
+        for lo, hi in (hi_d, lo_d, pre):
+            mask2 = (1 << h2) - 1
+            low = ((lo & mask2, hi & mask2) if lo >> h2 == hi >> h2
+                   else (0, mask2))
+            ranges += [(lo >> h2, hi >> h2), _span(lo, hi, h2), low]
+    else:
+        raise ValueError(f"no digit split in mode {mode!r}")
+    return min(r[0] for r in ranges), max(r[1] for r in ranges)
+
+
+def int8_digits(mode: str, w: int) -> bool:
+    """Every digit product of the mode fits int8 x int8 -> int32 MXU passes."""
+    lo, hi = digit_range(mode, w)
+    return -128 <= lo and hi <= 127
+
+
+def dot_path(mode: str, w: int, block_k: int, interpret: bool) -> str:
+    """How the kernel issues its digit products: ``"int8"`` on the chip
+    wherever :func:`int8_digits` holds (the v5e MXU's native integer pass;
+    an fp32 HIGHEST dot is several bf16 passes), else ``"fp32"`` where the
+    fp32 pass is exact (:func:`_fp32_dot_ok`; the CPU interpreter's fast
+    sgemm) and ``"int32"`` dots beyond.  mm1's single pass is int8.
+    Every path accumulates the same exact int32 products."""
+    if mode == "mm1" or (not interpret and int8_digits(mode, w)):
+        return "int8"
+    return "fp32" if _fp32_dot_ok(mode, w, block_k) else "int32"
+
+
+# Largest tile edge of the int8 path: (512, 512, 512) is 5.25 MB of
+# tune.space.vmem_footprint for kmm2; (512, 1024, 512) overflows v5e's
+# 16 MB of scoped VMEM.
+_INT8_TILE = 512
+
+
+def pow2_cover(n: int, lo: int = 8) -> int:
+    """Smallest power of two >= n, at least ``lo``."""
+    v = lo
+    while v < n:
+        v *= 2
+    return v
+
+
+def int8_tiles(shape, mode: str, w: int, m: int = 8
+               ) -> Optional[Tuple[int, int, int]]:
+    """Tiles (block_m, block_n, block_k) for an (M, K, N) GEMM whose digit
+    products run on the int8 path, or None where ``(mode, w)`` is not on it
+    (mm1 and the widths :func:`int8_digits` refuses keep the analytic
+    clamp).  Int8 passes are cheap enough that the per-step cost and the
+    in-register split of each tile set the rate, so tiles grow to 512:
+
+      * block_m: pow2 cover of M, at most 512;
+      * block_n: of 512/256/128 (at most the pow2 cover of N), the one
+        that pads N least, the larger on a tie (13824 = 27 x 512,
+        1280 = 5 x 256);
+      * block_k: 512 only where it pads K to the same length as the
+        analytic 256 clamp (the fp32 combine's correction reads padded K,
+        so another padding is another value), else that clamp.
+
+    A choice over ``tune.space.VMEM_BUDGET`` halves block_m until it fits
+    (kmm4 above w = 16, whose int32 carriers and 9 accumulators need it).
+    """
+    from repro.core.dispatch import DEFAULT_TILES, ExecPlan
+    from repro.tune.space import VMEM_BUDGET, vmem_footprint
+
+    mode = _resolve_mode(mode, w, m)
+    if mode == "mm1" or not int8_digits(mode, w):
+        return None
+    m_dim, k_dim, n_dim = shape
+    bm = min(_INT8_TILE, pow2_cover(m_dim))
+    n_cover = pow2_cover(n_dim)
+    if n_cover <= 128:
+        bn = n_cover
+    else:
+        bn = min((b for b in (_INT8_TILE, 256, 128) if b <= n_cover),
+                 key=lambda b: (-(-n_dim // b) * b, -b))
+    bk = min(DEFAULT_TILES[2], pow2_cover(k_dim))
+    kp = -(-k_dim // bk) * bk
+    if bk == DEFAULT_TILES[2] and kp % _INT8_TILE == 0:
+        bk = _INT8_TILE
+    plan = ExecPlan("fused_mm2" if mode == "mm2" else "fused", w, m=m,
+                    backend="pallas", block_m=bm, block_n=bn, block_k=bk,
+                    depth=2 if mode == "kmm4" else 1)
+    while vmem_footprint(plan) > VMEM_BUDGET and plan.block_m > 8:
+        plan = replace(plan, block_m=plan.block_m // 2)
+    return plan.block_m, plan.block_n, plan.block_k
+
+
 def _fused_kernel(*refs, mode: str, h: int, h2: int, z: int, nk: int,
-                  kp: int, seg: int, n_seg: int, fp32_dot: bool,
+                  kp: int, seg: int, n_seg: int, digit_dots: str,
                   combine_int32: bool, dequant: bool, grouped: bool,
                   ragged: bool, out_dtype):
     idx = 2
@@ -162,17 +300,16 @@ def _fused_kernel(*refs, mode: str, h: int, h2: int, z: int, nk: int,
         live &= seg_ids < n_seg
 
     def _dots(pairs, accs):
-        if fp32_dot:
-            # Exact fp32 digit products (see _fp32_dot_ok): this is the
-            # MXU's native number format; on CPU interpret mode it rides
-            # the fast sgemm path instead of the integer-matmul fallback.
-            hi_prec = jax.lax.Precision.HIGHEST
-            for (x, y), acc in zip(pairs, accs):
-                acc[...] += jnp.dot(x.astype(jnp.float32),
-                                    y.astype(jnp.float32),
-                                    precision=hi_prec).astype(jnp.int32)
-        else:
-            for (x, y), acc in zip(pairs, accs):
+        # Same exact int32 products on every path (see dot_path).
+        for (x, y), acc in zip(pairs, accs):
+            if digit_dots == "int8":
+                acc[...] += jnp.dot(x.astype(jnp.int8), y.astype(jnp.int8),
+                                    preferred_element_type=jnp.int32)
+            elif digit_dots == "fp32":
+                acc[...] += jnp.dot(
+                    x.astype(jnp.float32), y.astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST).astype(jnp.int32)
+            else:
                 acc[...] += jnp.dot(x, y, preferred_element_type=jnp.int32)
 
     def _accumulate():
@@ -295,14 +432,19 @@ def _combine_mm2(c1, c10, c01, c0, h: int, combine_int32: bool):
 _N_ACC = {"mm1": 1, "kmm2": 3, "mm2": 4, "kmm4": 9}
 
 
-def _resolve(w: int, m: int, mode: str, dequant: bool, combine_int32: bool,
-             out_dtype, interpret: Optional[bool]):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+def _resolve_mode(mode: str, w: int, m: int) -> str:
     if mode == "auto":
         mode = "mm1" if w <= m else "kmm2"
     if mode not in MODES:
         raise ValueError(f"unknown fused mode {mode!r}; choices {MODES}")
+    return mode
+
+
+def _resolve(w: int, m: int, mode: str, dequant: bool, combine_int32: bool,
+             out_dtype, interpret: Optional[bool]):
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    mode = _resolve_mode(mode, w, m)
     split = mode != "mm1"
     h = -(-w // 2) if split else 0
     h2 = -(-(h + 1) // 2) if mode == "kmm4" else 0
@@ -331,9 +473,11 @@ def _scratch_shapes(mode: str, block_m: int, block_n: int):
 def _fused_call(a, b, sx, sw, counts, *, grouped: bool, w: int, m: int,
                 mode: str, seg: Optional[int], block_m: int, block_n: int,
                 block_k: int, combine_int32: bool, out_dtype,
-                interpret) -> Array:
+                interpret, digit_dots: Optional[str] = None) -> Array:
     """Shared pallas_call builder; ``grouped`` adds the leading expert grid
     axis (every BlockSpec gains a size-1 leading block on the group index).
+    ``digit_dots`` overrides :func:`dot_path` (tests pin the int8 path in
+    interpret mode).
     """
     if (sx is None) != (sw is None):
         raise ValueError("pass both sx and sw for the dequant epilogue")
@@ -352,6 +496,8 @@ def _fused_call(a, b, sx, sw, counts, *, grouped: bool, w: int, m: int,
     b = _pad_tail(b.astype(carrier), (block_k, block_n))
     mp, kp = a.shape[-2:]
     np_ = b.shape[-1]
+    digit_dots = digit_dots or dot_path(mode, w, block_k, interpret)
+    _DIGIT_DOTS.inc(mode, digit_dots)
     body = (mp // block_m, np_ // block_n, kp // block_k)
     grid = lead + body if grouped else body
 
@@ -365,7 +511,7 @@ def _fused_call(a, b, sx, sw, counts, *, grouped: bool, w: int, m: int,
     kernel = functools.partial(
         _fused_kernel, mode=mode, h=h, h2=h2, z=z, nk=body[2], kp=kp,
         seg=seg or 0, n_seg=counts.shape[-1] if ragged else 0,
-        fp32_dot=mode != "mm1" and _fp32_dot_ok(mode, w, block_k),
+        digit_dots=digit_dots,
         combine_int32=combine_int32, dequant=dequant, grouped=grouped,
         ragged=ragged, out_dtype=out_dtype)
     in_specs = [spec((block_m, block_k), lambda i, j, kk: (i, kk)),

@@ -47,7 +47,8 @@ from repro.core.context import ExecContext, resolve_context
 from repro.core.dispatch import analytic_plan, select_plan
 from repro.core.kmm import kmm_n, max_exact_k, mm_n
 from repro.kernels import ops
-from repro.kernels.fused_gemm import fused_gemm, fused_gemm_grouped
+from repro.kernels.fused_gemm import (fused_gemm, fused_gemm_grouped,
+                                      int8_tiles, pow2_cover)
 from repro.obs import metrics as obs_metrics
 from repro.quant.quantize import quantize_symmetric
 
@@ -125,13 +126,6 @@ def _int_dot(qx: Array, qw: Array, w: int, m: int, dims,
               combine_dtype=jnp.float32)
 
 
-def _pow2_cover(n: int, lo: int = 8) -> int:
-    v = lo
-    while v < n:
-        v *= 2
-    return v
-
-
 def _shrink_tiles(plan, shape):
     """Clamp the analytic default tiles to the runtime shape (pow2 cover,
     floor 8): serve-sized GEMMs (decode M = batch, prefill M = bucket)
@@ -149,22 +143,30 @@ def _shrink_tiles(plan, shape):
     the local block, which never moves a bit.
     """
     return replace(plan,
-                   block_m=min(plan.block_m, _pow2_cover(shape[0])),
-                   block_n=min(plan.block_n, _pow2_cover(shape[2])),
-                   block_k=min(plan.block_k, _pow2_cover(shape[1])))
+                   block_m=min(plan.block_m, pow2_cover(shape[0])),
+                   block_n=min(plan.block_n, pow2_cover(shape[2])),
+                   block_k=min(plan.block_k, pow2_cover(shape[1])))
 
 
 def _fused_plan_for(shape, w: int, m: int, context: Optional[ExecContext]):
-    """Resolve + tile-clamp the pallas plan for a (local) GEMM shape, and
-    check the kernel's correctness bounds.  Returns None on any bound
-    failure (the XLA fallback applies, table-independent)."""
+    """Resolve + tile the pallas plan for a (local) GEMM shape, and check
+    the kernel's correctness bounds.  Analytic plans whose digit products
+    run on the int8 path take the kernel's own tile rule
+    (:func:`repro.kernels.fused_gemm.int8_tiles`), the rest the clamp of
+    :func:`_shrink_tiles`; table plans keep their tiles.  Returns None on
+    any bound failure (the XLA fallback applies, table-independent)."""
     from repro.tune.space import plan_accum_k_bound    # lazy: tune -> ops
 
     m_dim, k_dim, n_dim = shape
     table = context.resolve_table() if context is not None else None
     plan = select_plan(shape, w, m=m, backend="pallas", table=table)
     if plan.source == "analytic":
-        plan = _shrink_tiles(plan, shape)
+        tiles = int8_tiles(shape, _fused_mode(plan), w, m)
+        if tiles is None:
+            plan = _shrink_tiles(plan, shape)
+        else:
+            bm, bn, bk = tiles
+            plan = replace(plan, block_m=bm, block_n=bn, block_k=bk)
     # Correctness bounds (identical with or without a table; outside them
     # the XLA fallback applies either way, keeping numerics table-free).
     # The accumulator bound is plan-aware: MM2's pre-adder-free digits and

@@ -2,6 +2,10 @@
 staged Pallas path and the ref.py oracle across hostile tile/padding combos,
 the exact-int32 boundary, the grouped expert grid, and the dequant epilogue.
 """
+import functools
+from dataclasses import replace
+
+import jax
 import numpy as np
 import pytest
 import jax.numpy as jnp
@@ -9,8 +13,12 @@ import jax.numpy as jnp
 from repro.core.dispatch import ExecPlan, analytic_plan, select_plan
 from repro.core.kmm import max_exact_k
 from repro.kernels import ops
-from repro.kernels.fused_gemm import fused_gemm, fused_gemm_grouped
+from repro.kernels.fused_gemm import (
+    _fused_call, digit_range, dot_path, fused_gemm, fused_gemm_grouped,
+    int8_digits, int8_tiles,
+)
 from repro.kernels.ref import ref_int_gemm_i64
+from repro.obs import metrics as obs_metrics
 from repro.quant.qmatmul import (
     prequant_matmul, quantized_matmul, quantized_matmul_batched,
 )
@@ -442,3 +450,183 @@ def test_pallas_route_actually_runs_fused_at_serve_shapes():
         block_k=plan.block_k, out_dtype=jnp.float32))
     routed = np.asarray(quantized_matmul(x, wm, 12, 8, "auto", "pallas"))
     np.testing.assert_array_equal(routed, direct)
+
+
+# ---------------------------------------------------------------------------
+# Digit-dot paths: int8 MXU passes on the chip, fp32 in interpret mode.
+# ---------------------------------------------------------------------------
+
+# Every (mode, w) whose digit products fit int8 (kmm4 in its depth-2 window;
+# test_int8_range_rule_matches_brute_force names the rest).
+INT8_CASES = ([("kmm2", w) for w in range(9, 15)]
+              + [("mm2", w) for w in (15, 16)]
+              + [("kmm4", w) for w in range(17, 23)])
+
+
+def _extreme_operands(w: int, m: int, k: int, n: int, seed: int):
+    """Random w-bit rows/cols, then a block of all -2^(w-1) and a block of
+    all 2^(w-1)-1, so one launch covers every pairing of the extremes."""
+    rng = np.random.default_rng(seed)
+    lo, hi = -(1 << (w - 1)), (1 << (w - 1)) - 1
+    a = rng.integers(lo, hi + 1, (m, k))
+    b = rng.integers(lo, hi + 1, (k, n))
+    a[m // 3:2 * m // 3], a[2 * m // 3:] = lo, hi
+    b[:, n // 3:2 * n // 3], b[:, 2 * n // 3:] = lo, hi
+    return jnp.asarray(a, jnp.int32), jnp.asarray(b, jnp.int32)
+
+
+def _launch(a, b, counts=None, *, w, mode, path, combine_int32=False,
+            seg=None, tiles=(32, 32, 32)):
+    """``_fused_call`` in interpret mode with the digit-dot path pinned."""
+    bm, bn, bk = tiles
+    fn = functools.partial(
+        _fused_call, grouped=counts is not None or a.ndim == 3, w=w, m=8,
+        mode=mode, seg=seg, block_m=bm, block_n=bn, block_k=bk,
+        combine_int32=combine_int32, out_dtype=None, interpret=True,
+        digit_dots=path)
+    return np.asarray(jax.jit(fn)(a, b, None, None, counts))
+
+
+def _int32_ring(x: np.ndarray) -> np.ndarray:
+    """An int64 value as the int32 ring holds it (the int32 combine is exact
+    mod 2^32, so wrapping is the oracle's own low word)."""
+    return ((x + (1 << 31)) % (1 << 32)) - (1 << 31)
+
+
+@pytest.mark.parametrize("mode,w", INT8_CASES + [("ragged", 12)],
+                         ids=lambda v: str(v))
+def test_int8_digit_path_bit_identical(mode, w):
+    """The int8 digit-dot path equals the fp32 path bit for bit (fp32
+    combine) and the int64 oracle's int32 value (int32 combine), random and
+    extreme operands alike; the ragged grouped launch keeps its contract."""
+    if mode == "ragged":
+        e, seg, n_seg, k, n = 3, 8, 3, 70, 9
+        counts = jnp.asarray([[3, 8, 0], [0, 0, 0], [8, 1, 5]], jnp.int32)
+        pairs = [_extreme_operands(w, seg * n_seg, k, n, seed=i)
+                 for i in range(e)]
+        a = jnp.stack([p[0] for p in pairs])
+        b = jnp.stack([p[1] for p in pairs])
+        kw = dict(w=w, mode="kmm2", seg=seg)
+        out = _launch(a, b, counts, path="int8", **kw)
+        np.testing.assert_array_equal(
+            out, _launch(a, b, counts, path="fp32", **kw))
+        live = (np.arange(seg * n_seg)[None, :] % seg
+                < np.asarray(counts)[:, np.arange(seg * n_seg) // seg])
+        assert not out[~live].any()
+        return
+    assert int8_digits(mode, w)
+    a, b = _extreme_operands(w, 48, 70, 33, seed=w)
+    oracle = ref_int_gemm_i64(np.asarray(a), np.asarray(b))
+    out = _launch(a, b, w=w, mode=mode, path="int8")
+    np.testing.assert_array_equal(
+        out, _launch(a, b, w=w, mode=mode, path="fp32"),
+        err_msg=f"int8 != fp32 digit dots at {mode} w={w}")
+    exact = _launch(a, b, w=w, mode=mode, path="int8", combine_int32=True)
+    np.testing.assert_array_equal(exact.astype(np.int64), _int32_ring(oracle),
+                                  err_msg=f"int8 off the oracle at {mode} w={w}")
+
+
+def _brute_digit_range(mode: str, w: int):
+    """min/max over every operand of every pass, over every w-bit value."""
+    h = -(-w // 2)
+    z, lo, hi = 1 << (h - 1), None, None
+    for start in range(-(1 << (w - 1)), 1 << (w - 1), 1 << 20):
+        a = np.arange(start, min(start + (1 << 20), 1 << (w - 1)),
+                      dtype=np.int64)
+        a1, a0 = a >> h, (a & ((1 << h) - 1)) - z
+        ops_ = {"mm2": [a1, a0], "kmm2": [a1, a0, a1 + a0]}.get(mode)
+        if ops_ is None:                                  # kmm4
+            h2, ops_ = -(-(h + 1) // 2), []
+            for v in (a1, a1 + a0, a0):
+                v1, v0 = v >> h2, v & ((1 << h2) - 1)
+                ops_ += [v1, v0, v1 + v0]
+        cl = min(int(o.min()) for o in ops_)
+        ch = max(int(o.max()) for o in ops_)
+        lo = cl if lo is None else min(lo, cl)
+        hi = ch if hi is None else max(hi, ch)
+    return lo, hi
+
+
+def test_int8_range_rule_matches_brute_force():
+    """Which (mode, w) take int8 digit dots, each range checked against a
+    brute-force min/max over every w-bit value; and the counter sees one
+    trace per launch path."""
+    windows = {"kmm2": range(9, 15), "mm2": range(9, 17),
+               "kmm4": range(4, 27)}
+    fits = {mode: [w for w in ws if int8_digits(mode, w)]
+            for mode, ws in windows.items()}
+    assert fits == {"kmm2": list(range(9, 15)), "mm2": list(range(9, 17)),
+                    "kmm4": list(range(4, 23))}
+    assert digit_range("kmm2", 12) == (-64, 62)
+    assert digit_range("kmm4", 20) == (-16, 77)
+    assert digit_range("kmm4", 26) == (-64, 189)
+    for mode, ws in windows.items():
+        for w in ws:
+            assert digit_range(mode, w) == _brute_digit_range(mode, w), \
+                (mode, w)
+    # On the chip the digit products run int8 wherever they fit; in
+    # interpret mode they keep the exact fp32 pass (int32 beyond it).
+    assert dot_path("kmm2", 12, 512, interpret=False) == "int8"
+    assert dot_path("kmm4", 26, 256, interpret=False) == "fp32"
+    assert dot_path("kmm2", 12, 512, interpret=True) == "fp32"
+    assert dot_path("kmm2", 14, 2048, interpret=True) == "int32"
+    assert dot_path("mm1", 8, 256, interpret=True) == "int8"
+
+    counter = obs_metrics.get("repro_fused_digit_dot_total")
+    was_on = obs_metrics.enabled()
+    obs_metrics.enable()
+    counter.clear()
+    try:
+        a, b = _extreme_operands(12, 7, 45, 11, seed=0)
+        for _ in range(2):                      # one trace, two calls
+            fused_gemm(a, b, w=12, block_m=8, block_n=16, block_k=64)
+        _launch(a, b, w=12, mode="kmm2", path="int8")
+        fused_gemm(a // 16, b // 16, w=8, block_m=8, block_n=16, block_k=64)
+        assert counter.value("kmm2", "fp32") == 1
+        assert counter.value("kmm2", "int8") == 1
+        assert counter.value("mm1", "int8") == 1
+        assert counter.total() == 3
+    finally:
+        counter.clear()
+        if not was_on:
+            obs_metrics.disable()
+
+
+# stablelm-12b w12 serve GEMMs (prefill at 4096/2048/512, decode at 16 and
+# 8 lanes, the untied head), then ragged, tiny and pow2 shapes.
+TILE_SWEEP_SHAPES = [
+    (4096, 5120, 13824), (4096, 13824, 5120), (2048, 5120, 13824),
+    (2048, 13824, 5120), (512, 5120, 1280), (4096, 5120, 1280),
+    (16, 5120, 13824), (8, 5120, 100352), (1024, 5120, 5120),
+    (1, 64, 1), (33, 70, 17), (130, 700, 50), (300, 1280, 640),
+    (64, 768, 1000), (4, 2048, 8192), (512, 2048, 2048), (100, 300, 5000),
+]
+
+
+@pytest.mark.parametrize("w", [9, 12, 14, 16, 20, 24])
+def test_int8_tile_rule_keeps_padding_and_vmem(w):
+    """The int8 path's tiles (``int8_tiles``) pad K exactly as the analytic
+    256 clamp does, fit the VMEM budget, and leave table plans alone."""
+    from repro.core.context import ExecContext
+    from repro.quant.qmatmul import _fused_mode, _fused_plan_for, \
+        _shrink_tiles
+    from repro.tune.table import TuningTable
+
+    for shape in TILE_SWEEP_SHAPES:
+        plan = _fused_plan_for(shape, w, 8, None)
+        clamp = _shrink_tiles(analytic_plan(w, backend="pallas"), shape)
+        tiles = int8_tiles(shape, _fused_mode(plan), w)
+        assert (tiles is None) == (w == 24), (w, shape)
+        assert plan.tiles == (tiles or clamp.tiles), (w, shape)
+        k = shape[1]
+        assert -(-k // plan.block_k) * plan.block_k \
+            == -(-k // clamp.block_k) * clamp.block_k, (w, shape)
+        assert space.vmem_footprint(plan) <= space.VMEM_BUDGET, (w, shape)
+    shape = TILE_SWEEP_SHAPES[0]
+    base = analytic_plan(w, backend="pallas")
+    t = TuningTable()
+    t.put("pallas", shape, w,
+          replace(base, block_m=64, block_n=128, block_k=256))
+    plan = _fused_plan_for(shape, w, 8, ExecContext(backend="pallas",
+                                                    tuning_table=t))
+    assert plan.source.startswith("table") and plan.tiles == (64, 128, 256)

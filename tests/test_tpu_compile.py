@@ -13,13 +13,19 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.fused_gemm import fused_gemm, fused_gemm_grouped
+from repro.kernels.fused_gemm import (dot_path, fused_gemm,
+                                     fused_gemm_grouped, int8_tiles)
 from repro.quant.qmatmul import _fused_mode, _fused_plan_for
 
 # llama3.2-1b serve GEMMs: decode (4 slots) FFN up-projection, and a
 # 512-token prefill of the attention output projection.
 DECODE = (4, 2048, 8192)
 PREFILL = (512, 2048, 2048)
+# stablelm-12b w12 serve GEMMs (M, K, N): 4096- and 2048-token prefill of
+# the MLP, a 512-token prefill of a KV projection, a 16-lane decode of the
+# MLP and an 8-lane decode of the untied head.
+STABLELM = [(4096, 5120, 13824), (2048, 13824, 5120), (512, 5120, 1280),
+            (16, 5120, 13824), (8, 5120, 100352)]
 
 
 @pytest.fixture(scope="module")
@@ -58,6 +64,26 @@ def test_fused_serve_plan_compiles_for_v5e(one_chip, w, shape):
                           out_dtype=jnp.float32, interpret=False)
 
     text = _compiled_text(gemm, [((m, k), jnp.int32), ((k, n), jnp.int32),
+                                 ((m, 1), jnp.float32), ((1, n), jnp.float32)],
+                          one_chip)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("shape", STABLELM, ids=lambda s: "x".join(map(str, s)))
+def test_int8_tiles_compile_for_v5e(one_chip, shape):
+    """The int8 digit-dot path at the tiles its rule picks fits v5e's scoped
+    VMEM (up to (512, 512, 512); (512, 1024, 512) overflows it)."""
+    plan = _fused_plan_for(shape, 12, 8, None)
+    assert plan.tiles == int8_tiles(shape, "kmm2", 12)
+    assert dot_path("kmm2", 12, plan.block_k, interpret=False) == "int8"
+    m, k, n = shape
+
+    def gemm(a, b, sx, sw):
+        return fused_gemm(a, b, sx, sw, w=12, block_m=plan.block_m,
+                          block_n=plan.block_n, block_k=plan.block_k,
+                          out_dtype=jnp.bfloat16, interpret=False)
+
+    text = _compiled_text(gemm, [((m, k), jnp.int16), ((k, n), jnp.int16),
                                  ((m, 1), jnp.float32), ((1, n), jnp.float32)],
                           one_chip)
     assert "tpu_custom_call" in text
