@@ -1,8 +1,19 @@
-"""rwkv6-3b [ssm]: 32L d_model=2560 (attention-free) d_ff=8960 vocab=65536 —
-Finch, data-dependent decay [arXiv:2404.05892].
+"""rwkv6-3b [ssm]: 32L d_model=2560 (attention-free) d_ff=8960 vocab=65536,
+40 heads of 64, decay LoRA 64 -- the widths of RWKV-6 "Finch" 3B
+[arXiv:2404.05892].
 
-Channel-mix FFN modeled as a squared-ReLU MLP (RWKV's channel mix uses
-relu^2); time mix is the RWKV6 matrix-state recurrence in models/rwkv.py.
+The block is the matrix-state recurrence of models/rwkv.py with Finch's
+data-dependent decay, but it departs from the published Finch block in five
+ways:
+
+  1. the token-shift mix is a static learned blend per stream, where Finch
+     makes it data-dependent (the ``ddlerp`` LoRA);
+  2. the recurrence's output is normalised by one LayerNorm over the whole
+     width, where Finch uses a GroupNorm per head;
+  3. the channel mix is a squared-ReLU MLP with no receptance gate and no
+     token shift;
+  4. the pre-norms are RMSNorm, where Finch uses LayerNorm;
+  5. there is no ``ln0`` LayerNorm after the embedding.
 """
 from repro.models.config import Block, ModelConfig
 

@@ -1,11 +1,20 @@
-"""RWKV-6 ("Finch") block: attention-free linear recurrence with
-data-dependent per-channel decay.
+"""RWKV-6 time mix: attention-free linear recurrence with data-dependent
+per-channel decay.
 
 Per head (state S in R^{D x D}):  S_t = diag(w_t) S_{t-1} + k_t^T v_t,
 y_t = r_t (S_{t-1} + diag(u) k_t^T v_t).  The decay w_t is produced by a
 low-rank MLP on the token-shifted input (the v6 data-dependence).  The
 recurrence runs in fp32 (not an integer GEMM -> KMM inapplicable, DESIGN.md
 §6); the r/k/v/g/o projections ride the quantized KMM path.
+
+This is not the published Finch block (arXiv:2404.05892).  The time mix
+departs from it twice: the token shift blends each stream with a static
+learned mix (Finch's mix is data-dependent, the ``ddlerp`` LoRA), and the
+output is normalised by one LayerNorm over the model width (Finch: a
+GroupNorm per head).  The block around it (models/lm.py) departs three more
+times: a squared-ReLU channel mix with no receptance gate and no token
+shift, RMSNorm pre-norms where Finch has LayerNorm, and no ``ln0`` after the
+embedding.
 
 Implementation: time-step `lax.scan` for full sequences (state is
 (B, H, D, D), so an associative scan over matrices would materialize

@@ -87,6 +87,14 @@ _OCCUPANCY = obs_metrics.gauge(
 _FINISHED = obs_metrics.counter(
     "repro_serve_finished_total", "finished requests by stop reason",
     labels=("reason",))
+# Recurrence steps of the recurrent (rwkv / mamba) layers, one per token
+# position and layer: a prefill chunk runs its padded width of them (the
+# scan steps over pads too), a decode step one per layer for every lane at
+# once.
+_RECURRENT_STEPS = obs_metrics.counter(
+    "repro_serve_recurrent_steps_total",
+    "recurrence steps (token positions x recurrent layers) run by engine "
+    "steps, by phase", labels=("phase",))
 
 
 class Engine:
@@ -143,6 +151,8 @@ class Engine:
             set_active_table(ctx.tuning_table)
         self.context = ctx
         self.cfg = cfg
+        self._recurrent_layers = cfg.n_periods * sum(
+            b.kind in ("rwkv", "mamba") for b in cfg.pattern)
         self.mesh = mesh
         if mesh is not None:
             params = jax.device_put(
@@ -332,9 +342,12 @@ class Engine:
         tok = None
         with self._mesh_ctx(), obs_trace.span(
                 "serve.prefill", rid=req.stats.rid, slot=idx, off=ps.off,
-                width=width):
+                width=width, tokens=take):
             t0 = time.perf_counter()
             logits = self.executor.prefill(idx, toks, ps.off, last)
+            if self._recurrent_layers:
+                _RECURRENT_STEPS.inc("prefill",
+                                     by=width * self._recurrent_layers)
             ps.off += take
             if ps.off < plen:
                 jax.block_until_ready(logits)
@@ -411,6 +424,8 @@ class Engine:
                 logits = self.executor.decode(lanes, toks, pos)
                 sampled = self.executor.sample(
                     self._key, logits, temps, rids, steps)
+            if self._recurrent_layers:
+                _RECURRENT_STEPS.inc("decode", by=self._recurrent_layers)
             t1 = time.perf_counter()
             with obs_trace.span("serve.decode.wait"):
                 nxt = np.asarray(sampled)

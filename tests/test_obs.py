@@ -430,3 +430,45 @@ def test_step_phases_count_every_decode_step(tiny, obs_on):
     assert phases.count("finish") == decode.count()
     assert phases.count("admit") >= decode.count()
     assert phases.count("prefill") > 0
+
+
+@pytest.mark.parametrize("chunk", [None, 8], ids=["whole", "chunked"])
+def test_recurrent_steps_count_padded_prefill_and_decode(obs_on, chunk):
+    """``repro_serve_recurrent_steps_total``: a prefill chunk counts its
+    padded width times the recurrent layers (the scan runs over pads too),
+    a decode step the recurrent layers; ``serve.prefill`` carries the real
+    prompt tokens beside the padded width."""
+    from repro.configs import get_config
+    from repro.models import lm
+    from repro.serve.engine import Engine
+
+    cfg = get_config("rwkv6-3b", smoke=True)
+    params = lm.init_params(jax.random.PRNGKey(3), cfg)
+    eng = Engine(cfg, params, max_seq=32, batch_size=2, rng_seed=3,
+                 prefill_chunk=chunk)
+    stats = eng.generate(_requests(cfg, ((19, 4, 0.0), (9, 3, 0.0),
+                                         (5, 5, 0.0))))
+    layers = cfg.n_periods * len(cfg.pattern)
+    assert layers == 2
+    # (width, real tokens) of each prefill call: whole prompts take the
+    # bucket above them; 8-wide chunks split 19 into 8 + 8 + 3 and 9 into
+    # 8 + 1, each call 8 wide
+    calls = {None: [(32, 19), (16, 9), (8, 5)],
+             8: [(8, 8), (8, 8), (8, 3), (8, 8), (8, 1), (8, 5)]}[chunk]
+    steps = metrics.get("repro_serve_recurrent_steps_total")
+    assert steps.value("prefill") == sum(w for w, _ in calls) * layers
+    assert steps.value("decode") == stats.decode_steps * layers > 0
+    spans = [e["args"] for e in trace.events() if e["name"] == "serve.prefill"]
+    assert sorted((a["width"], a["tokens"]) for a in spans) == sorted(calls)
+
+
+def test_recurrent_steps_stay_zero_for_attention(tiny, obs_on):
+    from repro.serve.engine import Engine
+
+    cfg, params = tiny
+    eng = Engine(cfg, params, max_seq=32, batch_size=2, rng_seed=3)
+    eng.generate(_requests(cfg))
+    steps = metrics.get("repro_serve_recurrent_steps_total")
+    assert steps.value("prefill") == steps.value("decode") == 0
+    assert all("tokens" in e["args"] for e in trace.events()
+               if e["name"] == "serve.prefill")
