@@ -157,7 +157,7 @@ def param_sharding(params: Params, mesh: Mesh) -> Params:
 # Cache-leaf name -> axis index (within the (n_periods, slot, ...) layout)
 # that may shard over ``model``: attention kv-heads, rwkv heads, mamba inner.
 CACHE_MODEL_AXES = {
-    "k": 3,       # attn (n_periods, slot, Smax, K, D): kv-heads
+    "k": 3,       # attn (n_periods, slot, Smax, K*D): kv-heads x head dim
     "v": 3,
     "wkv": 2,     # rwkv (n_periods, slot, H, D, D): heads
     "ssm": 2,     # mamba (n_periods, slot, d_inner, d_state): inner dim

@@ -182,8 +182,17 @@ def attn_train(p: Params, x: Array, cfg, quant, name: str,
 
 
 def attn_cache_init(cfg, batch: int, max_seq: int, dtype) -> Params:
-    shape = (batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
+    """K/V caches (B, Smax, K*D): kv-heads and head dim share one minor
+    axis.  Kept as (K, D), a head dim off the TPU's 128-lane tile (160 at
+    stablelm-12b) pads every cache copy of the serve engine 1.6x and made
+    its decode program too large for the device beside the weights."""
+    shape = (batch, max_seq, cfg.n_kv_heads * cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+
+def _heads(c: Array, cfg) -> Array:
+    """(B, S, K*D) cache -> (B, S, K, D)."""
+    return c.reshape(c.shape[:2] + (cfg.n_kv_heads, cfg.head_dim))
 
 
 def cached_attention(q: Array, ck: Array, cv: Array, q_offset: Array, *,
@@ -246,10 +255,13 @@ def attn_prefill_chunk(p: Params, x: Array, cache: Params, offset: Array,
     q = rope(q, pos, cfg.rope_theta)
     k = rope(k, pos, cfg.rope_theta)
     ck = jax.lax.dynamic_update_slice(
-        cache["k"], k.astype(cache["k"].dtype), (0, offset, 0, 0))
+        cache["k"], k.reshape(b, c, -1).astype(cache["k"].dtype),
+        (0, offset, 0))
     cv = jax.lax.dynamic_update_slice(
-        cache["v"], v.astype(cache["v"].dtype), (0, offset, 0, 0))
-    out = cached_attention(q, ck, cv, offset, kv_valid=kv_valid)
+        cache["v"], v.reshape(b, c, -1).astype(cache["v"].dtype),
+        (0, offset, 0))
+    out = cached_attention(q, _heads(ck, cfg), _heads(cv, cfg), offset,
+                           kv_valid=kv_valid)
     out = out.reshape(b, c, cfg.q_dim)
     out = maybe_quantized_matmul(out, p["wo"], quant, f"{name}.wo")
     return out, {"k": ck, "v": cv}
@@ -263,7 +275,7 @@ def _as_batch_vec(pos, b: int) -> Array:
 def attn_decode(p: Params, x: Array, cache: Params, pos: Array, cfg, quant,
                 name: str, positions: Optional[Array] = None,
                 kv_valid: Optional[Array] = None) -> Tuple[Array, Params]:
-    """One-token decode: x (B, 1, d); cache k/v (B, Smax, K, D).
+    """One-token decode: x (B, 1, d); cache k/v (B, Smax, K*D).
 
     ``pos`` is the cache write index — a scalar (lock-step batch) or a (B,)
     vector (continuous batching: each slot at its own depth).  ``positions``
@@ -278,8 +290,8 @@ def attn_decode(p: Params, x: Array, cache: Params, pos: Array, cfg, quant,
     k = rope(k, rpos[:, None], cfg.rope_theta)
 
     def write(c, u, start):
-        return jax.lax.dynamic_update_slice(c, u.astype(c.dtype),
-                                            (start, 0, 0))
+        return jax.lax.dynamic_update_slice(
+            c, u.reshape(1, -1).astype(c.dtype), (start, 0))
 
     ck = jax.vmap(write)(cache["k"], k, pos_b)
     cv = jax.vmap(write)(cache["v"], v, pos_b)
@@ -287,7 +299,7 @@ def attn_decode(p: Params, x: Array, cache: Params, pos: Array, cfg, quant,
     g = cfg.n_heads // kh
     qv = q.reshape(b, kh, g, d)
     scores = jnp.einsum("bkgd,bskd->bkgs", qv,
-                        ck.astype(q.dtype)).astype(jnp.float32)
+                        _heads(ck, cfg).astype(q.dtype)).astype(jnp.float32)
     scores = scores * (d**-0.5)
     valid = (jnp.arange(ck.shape[1], dtype=jnp.int32)[None, :]
              <= pos_b[:, None])                              # (B, Smax)
@@ -295,7 +307,8 @@ def attn_decode(p: Params, x: Array, cache: Params, pos: Array, cfg, quant,
         valid = jnp.logical_and(valid, kv_valid)
     scores = jnp.where(valid[:, None, None, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bkgs,bskd->bkgd", probs, cv.astype(q.dtype))
+    out = jnp.einsum("bkgs,bskd->bkgd", probs,
+                     _heads(cv, cfg).astype(q.dtype))
     out = out.reshape(b, 1, cfg.q_dim)
     out = maybe_quantized_matmul(out, p["wo"], quant, f"{name}.wo")
     return out, {"k": ck, "v": cv}

@@ -62,6 +62,13 @@ _GEMM_ROUTES = obs_metrics.counter(
     "repro_quant_gemm_routes_total",
     "quantized-GEMM dispatch outcomes by backend and route",
     labels=("backend", "route"))
+# Where a quantized GEMM's weight operand came from: ``prequant`` (a stored
+# {"q", "scale"} record, quant/prequant.py) or ``runtime`` (quantized from
+# full precision inside the call).  One hit per jit trace, like the routes.
+_WEIGHT_SOURCE = obs_metrics.counter(
+    "repro_quant_weight_source_total",
+    "quantized-GEMM weight operands by source (prequant or runtime)",
+    labels=("source",))
 # Reasons the pallas route declined a GEMM (the table-independent XLA
 # fallbacks; mesh-negotiation fallbacks count in repro.dist's counter).
 _PALLAS_FALLBACKS = obs_metrics.counter(
@@ -78,6 +85,17 @@ def _quantize(x: Array, w: int, axis) -> Tuple[Array, Array]:
     produced by identical arithmetic.
     """
     return quantize_symmetric(x, w, axis=axis, keepdims=True)
+
+
+def _quantize_weight(wmat: Array, w: int, axis: int) -> Tuple[Array, Array]:
+    """Runtime per-output-channel weight quantization (the training path's
+    weights change every step); counted as the ``runtime`` source."""
+    _WEIGHT_SOURCE.inc("runtime")
+    qw, sw = _quantize(wmat, w, axis=axis)
+    # Keep the scale as a stored record holds it: left open, XLA folds its
+    # 1/qmax into the activation scale's (one 1/qmax**2 constant), which
+    # rounds the dequant differently from the prequantized path.
+    return qw, jax.lax.optimization_barrier(sw)
 
 
 def _dot_shape(qx: Array, qw: Array, dims) -> Tuple[int, int, int]:
@@ -258,6 +276,7 @@ def _sharded_pallas(qx: Array, qw: Array, sx: Array, sw: Array, w: int,
                                      counts=counts)(qx, qw, sx, sw)
     # Table/prior redirect inside the pinned fingerprint class: run the
     # staged plan shard-mapped through the production seam, dequant after.
+    qw = qw.astype(jnp.int32)           # staged kernels take int32 operands
     plan = replace(plan, shard=spec)
     if dense:
         acc = sg.sharded_run_plan(qx.reshape(m_dim, k_dim), qw, plan=plan,
@@ -345,6 +364,7 @@ def _fused_pallas(qx: Array, qw: Array, sx: Array, sw: Array, w: int, m: int,
             out_dtype=out_dtype)
     # Table/prior redirect inside the pinned fingerprint class: run the
     # selected plan through the production seam and dequant afterwards.
+    qw = qw.astype(jnp.int32)           # staged kernels take int32 operands
     if dense:
         acc = ops.run_plan(qx.reshape(m_dim, k_dim), qw, plan=plan)
         out = (acc.astype(jnp.float32)
@@ -371,7 +391,8 @@ def _quant_gemm(qx: Array, qw: Array, sx: Array, sw: Array, w: int, m: int,
     m-blocks; every other route applies the identical liveness mask to its
     output, so the contract — live rows unchanged, dead rows exact zeros —
     is backend-independent and the MoE combine sees the same tokens either
-    way.
+    way.  ``qw`` may be a stored record's narrow carrier (int8/int16): the
+    fused kernel reads it as it is, the other routes widen it to int32.
     """
     if context.backend not in BACKENDS:
         raise ValueError(f"unknown backend {context.backend!r}; "
@@ -385,7 +406,7 @@ def _quant_gemm(qx: Array, qw: Array, sx: Array, sw: Array, w: int, m: int,
         _GEMM_ROUTES.inc(context.backend, "xla_fallback")
     else:
         _GEMM_ROUTES.inc(context.backend, "xla")
-    acc = _int_dot(qx, qw, w, m, dims, context.force_mode)
+    acc = _int_dot(qx, qw.astype(jnp.int32), w, m, dims, context.force_mode)
     out = (acc * (sx * sw)).astype(out_dtype)
     if counts is not None:
         out = jnp.where(_ragged_row_mask(counts, seg, out.shape[1]),
@@ -409,7 +430,7 @@ def _qmm_core(x: Array, wmat: Array, w_bits: int, m: int,
 
 def _qmm_fwd_impl(x, wmat, w_bits, m, context):
     qx, sx = _quantize(x, w_bits, axis=-1)            # per-token
-    qw, sw = _quantize(wmat, w_bits, axis=0)          # per-out-channel
+    qw, sw = _quantize_weight(wmat, w_bits, axis=0)   # per-out-channel
     dims = (((x.ndim - 1,), (0,)), ((), ()))
     return _quant_gemm(qx, qw, sx, sw, w_bits, m, dims, context, x.dtype)
 
@@ -439,7 +460,7 @@ def _qbmm_core(x: Array, wmat: Array, w_bits: int, m: int,
 
 def _qbmm_fwd_impl(x, wmat, w_bits, m, context):
     qx, sx = _quantize(x, w_bits, axis=-1)            # per (expert, row)
-    qw, sw = _quantize(wmat, w_bits, axis=1)          # per (expert, channel)
+    qw, sw = _quantize_weight(wmat, w_bits, axis=1)   # per (expert, channel)
     dims = (((2,), (1,)), ((0,), (0,)))
     return _quant_gemm(qx, qw, sx, sw, w_bits, m, dims, context, x.dtype)
 
@@ -471,7 +492,7 @@ def _qbmm_ragged_core(x: Array, wmat: Array, counts: Array, w_bits: int,
 
 def _qbmm_ragged_fwd_impl(x, wmat, counts, w_bits, m, seg, context):
     qx, sx = _quantize(x, w_bits, axis=-1)            # per (expert, row)
-    qw, sw = _quantize(wmat, w_bits, axis=1)          # per (expert, channel)
+    qw, sw = _quantize_weight(wmat, w_bits, axis=1)   # per (expert, channel)
     dims = (((2,), (1,)), ((0,), (0,)))
     return _quant_gemm(qx, qw, sx, sw, w_bits, m, dims, context, x.dtype,
                        counts=counts, seg=seg)
@@ -562,19 +583,20 @@ def prequant_matmul(x: Array, wrec, w_bits: int, m: int = 8,
                     seg: Optional[int] = None) -> Array:
     """Serving path on pre-quantized weights ({"q", "scale"} records): skips
     the runtime weight quantization (see quant/prequant.py).  Inference-only
-    (not differentiable).  On the pallas backend the stored per-channel
-    scale threads straight into the fused kernel's dequant epilogue.
+    (not differentiable).  On the pallas backend the kernel reads the
+    stored int8/int16 carrier as it is, and the stored per-channel scale
+    threads straight into its dequant epilogue.
     ``counts``/``seg`` (batched only) run the ragged grouped contract of
     :func:`quantized_matmul_batched`."""
     ctx = _ctx(context, force_mode, backend, "prequant_matmul")
     qx, sx = _quantize(x, w_bits, axis=-1)
-    qw = wrec["q"].astype(jnp.int32)
+    _WEIGHT_SOURCE.inc("prequant")
     dims = (((2,), (1,)), ((0,), (0,))) if batched \
         else (((x.ndim - 1,), (0,)), ((), ()))
     if counts is not None and not batched:
         raise ValueError("ragged counts require batched=True")
     with ctx.activate():
-        return _quant_gemm(qx, qw, sx, wrec["scale"], w_bits, m, dims,
+        return _quant_gemm(qx, wrec["q"], sx, wrec["scale"], w_bits, m, dims,
                            ctx, x.dtype, counts=counts, seg=seg)
 
 

@@ -43,4 +43,4 @@ def quantize_symmetric(x: Array, bits: int, axis=None,
 
 def storage_dtype_for(bits: int):
     """Narrowest integer carrier for ``bits``-bit prequantized storage."""
-    return jnp.int8 if bits <= 8 else jnp.int16
+    return jnp.int8 if bits <= 8 else jnp.int16 if bits <= 16 else jnp.int32

@@ -5,7 +5,7 @@ more: every leaf lives in a *pool* with a row dimension at axis 1, and the
 mapping from decode slots to pool rows is data, not layout:
 
   * attention K/V leaves are split into fixed-size **pages** of
-    ``page_size`` tokens: pool layout ``(n_periods, n_pages, page, K, D)``,
+    ``page_size`` tokens: pool layout ``(n_periods, n_pages, page, K*D)``,
     slot -> pages through a ``(n_slots, pages_per_slot)`` int32 page table.
   * recurrent leaves (mamba conv/ssm, rwkv shift/wkv) are a single state
     row per slot: pool layout ``(n_periods, n_state_rows, ...)``, slot ->

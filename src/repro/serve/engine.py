@@ -28,6 +28,11 @@ Admission order and per-(request, step) sampling keys make output
 token-identical to sequential single-request generation — independent of
 slot count, decode-bucket width, prefill chunking and prefix-cache hits.
 
+With quantization enabled the engine serves prequantized weights
+(:func:`repro.quant.prequant.prequantize`): each quantizable weight leaf is
+stored as ``{"q": intN, "scale"}`` once, at construction, and no GEMM call
+re-quantizes a weight.
+
 Pass ``mesh=`` to serve sharded: params take the ``repro.dist.sharding``
 param rules, the page pools take the page-pool rules (pages over ``data``,
 kv-heads over ``model``), and the executor's jits run under the mesh so
@@ -55,6 +60,7 @@ from repro.core.context import ExecContext, resolve_context
 from repro.dist import sharding as dist_sharding
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
+from repro.quant.prequant import prequantize
 from repro.serve.cache import PagedCachePool, PrefixCache, default_page_size
 from repro.serve.executor import Executor
 from repro.serve.scheduler import (MIN_BUCKET, Request, RequestStats,
@@ -154,6 +160,11 @@ class Engine:
         self._recurrent_layers = cfg.n_periods * sum(
             b.kind in ("rwkv", "mamba") for b in cfg.pattern)
         self.mesh = mesh
+        if cfg.quant.enabled:
+            # Weights do not change while serving: quantize each once here
+            # instead of at every GEMM call.  The caller's tree is left as
+            # it was.
+            params = prequantize(params, cfg.quant)
         if mesh is not None:
             params = jax.device_put(
                 params, dist_sharding.param_sharding(params, mesh))

@@ -63,7 +63,7 @@ class Executor:
         def gather(pools, prows, srows):
             def leaf(path, pool_arr):
                 if is_paged_leaf(path):
-                    lanes = pool_arr[:, prows]   # (np, W, pps, page, K, D)
+                    lanes = pool_arr[:, prows]   # (np, W, pps, page, K*D)
                     w = prows.shape[0]
                     return lanes.reshape(
                         (pool_arr.shape[0], w, pps * page)
