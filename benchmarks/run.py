@@ -1,16 +1,11 @@
-"""Benchmark harness entry: one reproduction per paper table/figure plus the
-wall-time microbench and the dry-run roofline table.
+"""Paper-table reproductions: one function per table/figure of the paper.
 
-    PYTHONPATH=src python -m benchmarks.run [--skip-walltime]
+    PYTHONPATH=src python -m benchmarks.run
 
 Prints ``name,us_per_call,derived`` CSV rows followed by CHECK lines that
-assert the paper's claims against our implementation, and writes one
-machine-readable ``BENCH_<group>.json`` per benchmark group (paper_tables /
-walltime / serve / roofline) at the repo root so the perf trajectory —
-tokens/s, TTFT, GEMM wall-times — is tracked across PRs.
-
-``--tuning-table tuned/default.json`` installs a repro.tune kernel
-variant/tile table before any benchmark runs (see DESIGN.md §10).
+assert the paper's claims against our implementation, and writes them as
+``BENCH_paper_tables.json`` at the repo root.  The speed of the serving
+system is measured on the chip by ``bench/``, not here.
 """
 from __future__ import annotations
 
@@ -39,9 +34,8 @@ def _emit(rows, checks, csv_lines, check_lines):
 
 
 def write_bench_json(group: str, rows, checks, out_dir: str) -> str:
-    """Persist one benchmark group as BENCH_<group>.json (machine-readable:
-    every row dict verbatim — tokens/s, TTFT percentiles, GEMM us_per_call —
-    plus the CHECK verdicts)."""
+    """Persist one benchmark group as BENCH_<group>.json (every row dict
+    verbatim plus the CHECK verdicts)."""
     doc = {
         "bench": group,
         "rows": list(rows),
@@ -58,91 +52,33 @@ def write_bench_json(group: str, rows, checks, out_dir: str) -> str:
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--skip-walltime", action="store_true")
-    ap.add_argument("--skip-serve", action="store_true")
-    ap.add_argument("--dryrun-dir", default="experiments/dryrun")
     ap.add_argument("--json-dir", default=REPO_ROOT,
-                    help="where BENCH_<group>.json files land "
+                    help="where BENCH_paper_tables.json lands "
                          "(default: repo root)")
     ap.add_argument("--no-json", action="store_true",
-                    help="skip writing BENCH_<group>.json files")
-    ap.add_argument("--tuning-table", default=None,
-                    help="repro.tune table JSON to install before running")
-    ap.add_argument("--only", default=None,
-                    choices=["paper_tables", "walltime", "serve", "sharded",
-                             "roofline"],
-                    help="run a single benchmark group (e.g. the CI "
-                         "bench-regression step runs --only walltime)")
-    ap.add_argument("--roofline-smoke", action="store_true",
-                    help="measure traffic on the tiny SMOKE_SHAPES instead "
-                         "of the tuned deep-K bench shapes (CI obs-smoke)")
+                    help="skip writing BENCH_paper_tables.json")
     args = ap.parse_args()
 
-    if args.tuning_table:
-        from repro.core.context import ExecContext
-        from repro.tune import set_active_table
-        set_active_table(
-            ExecContext(tuning_table=args.tuning_table).resolve_table())
-
-    from benchmarks import bench_roofline, bench_serve, bench_sharded, \
-        bench_walltime, paper_tables
-
-    csv_lines = ["name,us_per_call,derived"]
-    check_lines = []
-    json_paths = []
-
-    def record(group, rows, checks):
-        _emit(rows, checks, csv_lines, check_lines)
-        if not args.no_json:
-            json_paths.append(write_bench_json(group, rows, checks,
-                                               args.json_dir))
-
-    def wants(group: str) -> bool:
-        return args.only is None or args.only == group
+    from benchmarks import paper_tables
 
     t0 = time.time()
-    if wants("paper_tables"):
-        pt_rows, pt_checks = [], []
-        for fn in (paper_tables.fig5, paper_tables.fig11, paper_tables.fig12,
-                   paper_tables.table1, paper_tables.table2,
-                   paper_tables.table3):
-            rows, checks = fn()
-            pt_rows.extend(rows)
-            pt_checks.extend(checks)
-        record("paper_tables", pt_rows, pt_checks)
-
-    if wants("walltime") and not args.skip_walltime:
-        rows = bench_walltime.run()
-        record("walltime", rows, bench_walltime.checks(rows))
-
-    if wants("serve") and not args.skip_serve:
-        rows = bench_serve.run()
-        record("serve", rows, bench_serve.checks(rows))
-
-    if wants("sharded") and not args.skip_walltime:
-        # shard-mapped pallas vs GSPMD XLA on a 2x4 host-device mesh
-        # (own subprocess: device count must be set before jax init)
-        rows = bench_sharded.run()
-        record("sharded", rows, bench_sharded.checks(rows))
-
-    if wants("roofline"):
-        # Measured traffic (compiled bytes-accessed, repro.obs.traffic) of
-        # the fused / staged / xla GEMM paths vs the analytic plane-traffic
-        # model, plus the dry-run roofline table when artifacts exist.
-        from repro.obs import traffic
-        shapes = traffic.SMOKE_SHAPES if args.roofline_smoke \
-            else traffic.DEFAULT_SHAPES
-        t_rows = traffic.all_traffic_rows(shapes)
-        d_rows = bench_roofline.run(args.dryrun_dir)
-        record("roofline", t_rows + d_rows,
-               traffic.traffic_checks(t_rows) + bench_roofline.checks(d_rows))
-
+    rows, checks = [], []
+    for fn in (paper_tables.fig5, paper_tables.fig11, paper_tables.fig12,
+               paper_tables.table1, paper_tables.table2,
+               paper_tables.table3):
+        r, c = fn()
+        rows.extend(r)
+        checks.extend(c)
+    csv_lines = ["name,us_per_call,derived"]
+    check_lines = []
+    _emit(rows, checks, csv_lines, check_lines)
     print("\n".join(csv_lines))
     print()
     print("\n".join(check_lines))
+    if not args.no_json:
+        path = write_bench_json("paper_tables", rows, checks, args.json_dir)
+        print(f"wrote {path}")
     n_fail = sum(1 for line in check_lines if "FAIL" in line)
-    for p in json_paths:
-        print(f"wrote {p}")
     print(f"\n{len(check_lines) - n_fail}/{len(check_lines)} checks passed "
           f"({time.time() - t0:.1f}s)")
     if n_fail:

@@ -139,9 +139,9 @@ def check_exact(label: str, got, want: np.ndarray) -> None:
 def kernel_phase(seed: int) -> None:
     import jax.numpy as jnp
 
-    from repro.kernels.fused_gemm import fused_gemm, fused_gemm_grouped
+    from repro.kernels.fused_gemm import (fused_gemm, fused_gemm_grouped,
+                                          plan_for)
     from repro.kernels.ref import ref_int_gemm_i64
-    from repro.quant.qmatmul import _fused_plan_for
 
     rng = np.random.default_rng(seed)
     m, k, n = KERNEL_SHAPE
@@ -149,8 +149,7 @@ def kernel_phase(seed: int) -> None:
         lim = 1 << (w - 1)
         a = rng.integers(-lim, lim, (m, k))
         b = rng.integers(-lim, lim, (k, n))
-        plan = _fused_plan_for(KERNEL_SHAPE, w, 8, None)
-        tiles = (plan.block_m, plan.block_n, plan.block_k)
+        tiles = plan_for(KERNEL_SHAPE, w).tiles
         out = fused_gemm(jnp.asarray(a, jnp.int32), jnp.asarray(b, jnp.int32),
                          w=w, mode=mode, block_m=tiles[0], block_n=tiles[1],
                          block_k=tiles[2], combine_int32=True)
@@ -165,8 +164,7 @@ def kernel_phase(seed: int) -> None:
     b = rng.integers(-lim, lim, (MOE_E, MOE_K, MOE_N))
     counts = rng.integers(0, SEG + 1, (MOE_E, N_SEG))
     counts[::7] = 0                       # experts that drew no token
-    plan = _fused_plan_for((c, MOE_K, MOE_N), MOE_W, 8, None)
-    tiles = (plan.block_m, plan.block_n, plan.block_k)
+    tiles = plan_for((c, MOE_K, MOE_N), MOE_W).tiles
     out = fused_gemm_grouped(
         jnp.asarray(a, jnp.int32), jnp.asarray(b, jnp.int32),
         counts=jnp.asarray(counts, jnp.int32), w=MOE_W, seg=SEG,

@@ -43,7 +43,7 @@ def main():
     out = np.asarray(int_gemm(jnp.array(a), jnp.array(b), w=12,
                               backend="pallas"))
     ref = ref_int_gemm_i64(a, b).astype(np.float64)
-    print(f"Pallas kmm2_gemm: max rel err "
+    print(f"Pallas fused_gemm (kmm2): max rel err "
           f"{np.abs(out-ref).max()/np.abs(ref).max():.2e}")
 
     # --- 4. the paper's cost models ------------------------------------------

@@ -9,9 +9,9 @@ Tensor form: the contraction axis K is blocked into groups of ``p``; products
 within a group reduce first (the narrow pre-sum), then group sums reduce into
 the running accumulator.  The result is bit-identical to a flat accumulation;
 what changes is the *hardware* cost, which :mod:`repro.core.complexity` and
-:mod:`repro.core.area` account for, and which the Pallas kernel
-(:mod:`repro.kernels.kmm_gemm`) realizes structurally with a per-K-tile
-VMEM pre-accumulator.
+:mod:`repro.core.area` account for.  The fused Pallas kernel
+(:mod:`repro.kernels.fused_gemm`) keeps its digit accumulators in VMEM
+across the K grid.
 """
 from __future__ import annotations
 

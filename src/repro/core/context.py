@@ -3,8 +3,8 @@
 Before this module, the knobs that pick an execution path were scattered as
 ad-hoc kwargs — ``backend=`` on :func:`repro.kernels.ops.int_gemm`,
 ``quant_backend=`` on the serve :class:`~repro.serve.engine.Engine`,
-``tuning_table=`` in three places, ``mesh=`` in two, ``force_mode`` threaded
-positionally through the ``custom_vjp`` entry points.  ``ExecContext`` is the
+``mesh=`` in two, ``force_mode`` threaded positionally through the
+``custom_vjp`` entry points.  ``ExecContext`` is the
 single replacement: a frozen dataclass carrying
 
   * ``backend``      — "xla" (plain dot_generals, GSPMD-partitionable) or
@@ -12,15 +12,11 @@ single replacement: a frozen dataclass carrying
   * ``mesh``         — a ``jax.sharding.Mesh`` when GEMMs should run
                        shard-mapped (see :mod:`repro.dist.shard_gemm`), or
                        None for single-device / ambient-GSPMD execution;
-  * ``tuning_table`` — a :class:`repro.tune.TuningTable` (or a path to one)
-                       consulted by plan selection, without mutating the
-                       process-global registry;
   * ``force_mode``   — "auto" (the paper's dispatch rule) or "mm2" (the
                        conventional-baseline override used by benchmarks).
 
-It is hashable (the table is excluded from eq/hash — tables are
-numerics-pinned, so two contexts differing only in table compute identical
-values) and is consumed at trace time, never inside traced computations.
+It is hashable and is consumed at trace time, never inside traced
+computations.
 
 Migration table (DESIGN.md §12):
 
@@ -29,21 +25,17 @@ Migration table (DESIGN.md §12):
     quantized_matmul(..., backend="p")     quantized_matmul(..., context=ctx)
     quantized_matmul(..., force_mode="m")  ctx = ExecContext(force_mode="m")
     Engine(..., quant_backend="pallas")    Engine(..., context=ctx)
-    Engine(..., tuning_table=path)         ctx = ExecContext(tuning_table=path)
-    select_plan(..., backend=, table=)     select_plan(..., context=ctx)
     int_gemm(..., backend="pallas")        int_gemm(..., context=ctx)
-    TrainConfig(tuning_table=path)         TrainConfig(context=ctx)
 
 The old kwargs keep working through :func:`resolve_context` shims that emit
 one ``DeprecationWarning`` naming every legacy kwarg used.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import warnings
-from dataclasses import dataclass, field
-from typing import Any, Optional, Tuple
+from dataclasses import dataclass
+from typing import Any, Optional
 
 __all__ = ["ExecContext", "resolve_context"]
 
@@ -61,10 +53,6 @@ class ExecContext:
 
     backend: str = "xla"            # "xla" | "pallas"
     mesh: Optional[Any] = None      # jax.sharding.Mesh | None
-    # Excluded from eq/hash: TuningTable is a mutable dataclass, and tables
-    # are numerics-pinned — they change speed, never values — so contexts
-    # differing only in table are interchangeable as static/cache keys.
-    tuning_table: Optional[Any] = field(default=None, compare=False)
     force_mode: str = "auto"        # "auto" | "mm2"
 
     def __post_init__(self):
@@ -80,41 +68,10 @@ class ExecContext:
     def replace(self, **kw) -> "ExecContext":
         return dataclasses.replace(self, **kw)
 
-    def resolve_table(self):
-        """The context's table as a loaded TuningTable (paths are loaded
-        lazily, once per call — callers that care should pass the loaded
-        object), or None."""
-        if self.tuning_table is None:
-            return None
-        from repro.tune.table import TuningTable
-        if isinstance(self.tuning_table, TuningTable):
-            return self.tuning_table
-        return TuningTable.load(self.tuning_table)
-
-    def activate(self):
-        """Context manager installing ``tuning_table`` into the process-global
-        registry for the enclosed trace (no-op when the context carries no
-        table — the currently active table, if any, stays in effect)."""
-        if self.tuning_table is None:
-            return contextlib.nullcontext()
-        from repro.tune.table import use_table
-        return use_table(self.tuning_table)
-
-    def local_gemm_shape(self, shape: Tuple[int, int, int]
-                         ) -> Tuple[int, int, int]:
-        """Per-shard (M, K, N) of a GEMM under this context's mesh (the
-        canonical serve sharding: M over data axes, N over model, K
-        replicated).  Identity without a mesh."""
-        if self.mesh is None:
-            return shape
-        from repro.tune.space import local_shape
-        return local_shape(shape, self.mesh)
-
 
 def resolve_context(context: Optional[ExecContext], *, what: str,
                     backend: Optional[str] = None,
                     force_mode: Optional[str] = None,
-                    tuning_table: Optional[Any] = None,
                     mesh: Optional[Any] = None,
                     _defaults: Optional[ExecContext] = None) -> ExecContext:
     """Fold legacy kwargs into an :class:`ExecContext` (the deprecation shim).
@@ -127,7 +84,6 @@ def resolve_context(context: Optional[ExecContext], *, what: str,
     """
     legacy = {k: v for k, v in (("backend", backend),
                                 ("force_mode", force_mode),
-                                ("tuning_table", tuning_table),
                                 ("mesh", mesh)) if v is not None}
     if context is not None:
         if legacy:

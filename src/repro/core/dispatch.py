@@ -14,20 +14,9 @@ KMM halves the width (+1 carry bit) until digits fit the multiplier.
 from __future__ import annotations
 
 import enum
-import functools
 import math
-from dataclasses import dataclass, replace
-from typing import List, Optional, Tuple
-
-from repro.obs import metrics as obs_metrics
-
-# Plan-selection traffic by (variant, backend, bucketed shape).  select_plan
-# runs at trace time (host Python) — the counter sees one hit per trace, not
-# per executed call, and costs one flag test when metrics are disabled.
-_PLANS_SELECTED = obs_metrics.counter(
-    "repro_plans_selected_total",
-    "select_plan resolutions by variant/backend/bucketed shape",
-    labels=("variant", "backend", "bucket", "source"))
+from dataclasses import dataclass
+from typing import List
 
 
 class Mode(enum.Enum):
@@ -122,318 +111,3 @@ def efficiency_roof(w: int, m: int) -> float:
 def schedule(widths: List[int], m: int = 8) -> List[Plan]:
     """Plan a mixed-precision workload (one Plan per layer bitwidth)."""
     return [select_mode(w, m) for w in widths]
-
-
-# ---------------------------------------------------------------------------
-# Execution plans + table-backed selection (repro.tune registry seam).
-# ---------------------------------------------------------------------------
-
-# Kernel variants the tuner can pick between.  "mm1"/"kmm2"/"mm2" are the
-# paper's modes (executed on the Pallas kernels or the XLA digit recursion
-# depending on ``backend``); "fused" is the single-pass Pallas kernel
-# (in-kernel digit split + zero-point correction + optional dequant
-# epilogue, covering the MM1 window, single-level KMM2 at depth 1, and
-# 4-digit depth-2 KMM at depth 2 — see kernels/fused_gemm.py);
-# "fused_mm2" is the same kernel in its 4-pass conventional boundary mode
-# (valid through w <= 2m, the analytic default for the (2m-2, 2m] window
-# and a tuner alternative inside the KMM2 window); "xla_ref" is a single
-# fused int32 dot_general (valid only within the int32 headroom bound);
-# "ffip" is the literal free-pipeline inner-product reference (tiny shapes
-# only); "strassen" / "strassen+kmm2" are one tile-level Strassen split
-# whose 7 sub-GEMMs re-enter run_plan at w+1 — on the analytic XLA exact
-# plan and on the fused Pallas kernel respectively (core/strassen.py) —
-# exact-int by construction (int32 ring combines), valid only inside the
-# composed headroom bound tune.space.strassen_k_bound derives.
-VARIANTS = ("mm1", "kmm2", "mm2", "fused", "fused_mm2", "xla_ref", "ffip",
-            "strassen", "strassen+kmm2")
-
-# Integer core, no fp32 combine anywhere.  The strassen variants belong
-# here unconditionally: validate() rejects them without combine_int32, and
-# their sub-plans are themselves exact-int.
-_EXACT_VARIANTS = ("mm1", "xla_ref", "ffip", "strassen", "strassen+kmm2")
-
-# Variants whose recorded tiles reflect a real Pallas measurement (the
-# tiles-only adoption path in select_plan).  The strassen variants are
-# deliberately excluded: their tiles were measured on the *half-shape*
-# sub-GEMMs, so adopting them for a full-shape fused plan would transplant
-# geometry tuned for a different problem.
-_TILED_VARIANTS = ("mm1", "kmm2", "mm2", "fused", "fused_mm2")
-
-
-@dataclass(frozen=True)
-class GemmShardSpec:
-    """How one GEMM's (M, K, N) dims map onto mesh axes.
-
-    ``m_axes``/``n_axes`` shard the output tile grid (each shard runs the
-    kernel on its local block; no cross-shard arithmetic, so values are
-    bit-identical to the unsharded kernel).  ``k_axes`` splits the
-    contraction: each shard computes a partial product and the results are
-    ``psum``-combined — exact for exact-int plans (int32 partials sum to the
-    true product), but a *different fp32 rounding* for fp32-combine plans,
-    which is why ``k_axes`` participates in :func:`numerics_fingerprint` and
-    the model-facing negotiation in :mod:`repro.dist.shard_gemm` never
-    proposes it for fp32 plans.
-    """
-
-    m_axes: Tuple[str, ...] = ()
-    n_axes: Tuple[str, ...] = ()
-    k_axes: Tuple[str, ...] = ()
-    e_axes: Tuple[str, ...] = ()   # expert/group dim of grouped GEMMs
-
-
-@dataclass(frozen=True)
-class ExecPlan:
-    """A fully-resolved way to execute one integer GEMM.
-
-    Where :class:`Plan` is the paper's analytic mode decision (bitwidth ->
-    mode), an ``ExecPlan`` adds everything the software stack needs to *run*
-    it: kernel variant, execution backend, tile sizes, combine precision and
-    digit-recursion depth.  Frozen + hashable so it can be a jit static arg.
-    """
-
-    variant: str                 # one of VARIANTS
-    w: int                       # input bitwidth
-    m: int = 8                   # multiplier bitwidth
-    backend: str = "xla"         # "xla" | "pallas" (digit variants only)
-    block_m: int = 128
-    block_n: int = 128
-    block_k: int = 256
-    combine_int32: bool = False  # int32 post-adder (exact) vs fp32
-    depth: int = 1               # digit-recursion levels (digits = 2**depth)
-    source: str = "analytic"     # "analytic" | "table" | "prior" (+notes)
-    # Fused-kernel epilogue: "none" (raw int32/fp32 accumulator out) or
-    # "dequant" (per-token x per-channel scales applied in-kernel).  A
-    # call-site property, never persisted in tuning tables — quant/qmatmul
-    # stamps it onto the selected plan before running.
-    epilogue: str = "none"
-    # Mesh layout for shard-mapped execution (repro.dist.shard_gemm); None
-    # runs the kernel unsharded.  A call-site property like ``epilogue`` —
-    # stamped by the sharded dispatch path, never persisted in tables
-    # (table.put() serializes only _ENTRY_FIELDS).
-    shard: Optional[GemmShardSpec] = None
-
-    @property
-    def digits(self) -> int:
-        if self.variant == "fused":
-            return 2 ** self.depth      # depth 0 in the MM1 window
-        if self.variant == "fused_mm2":
-            return 2
-        return 2 ** self.depth if self.variant in ("kmm2", "mm2") else 1
-
-    @property
-    def mode(self) -> Optional[Mode]:
-        if self.variant == "fused":
-            return Mode.KMM2 if self.w > self.m else Mode.MM1
-        if self.variant in ("mm2", "fused_mm2"):
-            return Mode.MM2
-        if self.variant == "kmm2":
-            return Mode.KMM2
-        if self.variant in ("mm1", "xla_ref"):
-            return Mode.MM1
-        return None
-
-    @property
-    def tiles(self) -> Tuple[int, int, int]:
-        return (self.block_m, self.block_n, self.block_k)
-
-    @property
-    def is_exact_int(self) -> bool:
-        """True when the plan computes the mathematically exact integer
-        product in int32 (validity-checked against ``max_exact_k``)."""
-        if self.variant == "fused" and self.w <= self.m:
-            return True              # MM1-window core: one int8 MXU pass
-        return self.combine_int32 or self.variant in _EXACT_VARIANTS
-
-
-def numerics_fingerprint(plan: ExecPlan):
-    """Two plans with equal fingerprints produce bit-identical outputs on the
-    same operands (given both pass validity).  Exact-int plans all compute
-    the same integer — including the strassen tile-split variants, whose
-    int32 ring combines reproduce the plain product exactly inside their
-    composed headroom bound — so a tuned table may swap strassen in or out
-    of the exact class without moving a bit; fp32-combine plans are keyed by
-    everything that changes
-    rounding: variant, recursion depth and backend (the Pallas path runs on
-    centered digit planes + zero-point correction, the XLA path on raw
-    digits — same value, different fp32 rounding).  The fused kernel applies
-    the *identical* fp32 operation sequence as the staged Pallas KMM2 path
-    (asserted by tests/test_fused_gemm.py), so it shares that class; the
-    epilogue is part of the fingerprint because a dequantized output is a
-    different value than the raw accumulator.
-
-    Sharding (DESIGN.md §12): M/N sharding replicates K, so every output
-    element sees the full-K arithmetic of the unsharded kernel — not part of
-    the fingerprint.  K sharding splits the fp32 accumulation order, so
-    ``shard.k_axes`` IS part of the fp32 fingerprint (exact-int plans sum
-    int32 partials exactly and stay in the "exact" class)."""
-    if plan.is_exact_int:
-        return ("exact", plan.epilogue)
-    variant = {"fused": "kmm2", "fused_mm2": "mm2"}.get(plan.variant,
-                                                        plan.variant)
-    k_axes = plan.shard.k_axes if plan.shard is not None else ()
-    return ("fp32", variant, plan.depth, plan.backend, plan.epilogue, k_axes)
-
-
-DEFAULT_TILES = (128, 128, 256)
-
-
-def analytic_plan(w: int, m: int = 8, *, backend: str = "xla",
-                  exact: bool = False) -> ExecPlan:
-    """The paper's dispatch rule as an ExecPlan with default tiles.
-
-    On ``backend="pallas"`` every window through depth-2 recursion routes
-    to the fused single-pass kernel (kernels/fused_gemm.py) —
-    numerics-identical to the staged kernels (same fingerprint class), one
-    HBM round-trip instead of ~6: MM1 and single-level KMM2 as "fused", the
-    (2m-2, 2m] boundary as "fused_mm2" (4 passes), and 4-digit recursion
-    (``kmm_levels_needed(w, m) == 2``) as "fused" at depth 2 (9 passes).
-    Only depth >= 3 keeps the staged variants.
-    """
-    plan = select_mode(w, m)
-    bm, bn, bk = DEFAULT_TILES
-    variant = plan.mode.value
-    depth = max(plan.recursion, 1) if plan.mode is not Mode.MM1 else 0
-    combine_int32 = exact
-    if backend == "pallas" and (
-            plan.mode is Mode.MM1
-            or (plan.mode is Mode.KMM2 and plan.recursion <= 2)):
-        variant = "fused"
-        combine_int32 = exact or plan.mode is Mode.MM1
-    elif backend == "pallas" and plan.mode is Mode.MM2:
-        variant = "fused_mm2"
-    return ExecPlan(variant=variant, w=w, m=m, backend=backend,
-                    block_m=bm, block_n=bn, block_k=bk,
-                    combine_int32=combine_int32, depth=depth)
-
-
-def _padded(dim: int, block: int) -> int:
-    return -(-dim // block) * block
-
-
-def select_plan(shape: Tuple[int, int, int], w: int, *, m: int = 8,
-                backend: str = "xla", exact: bool = False,
-                table=None, pin_numerics: bool = True,
-                context=None) -> ExecPlan:
-    """Table-backed execution-plan selection for an (M, K, N) integer GEMM.
-
-    ``context`` (an :class:`repro.core.context.ExecContext`) supersedes the
-    scattered kwargs: its ``backend`` wins over ``backend=``, its
-    ``tuning_table`` is consulted (without touching the process-global
-    registry), and under ``context.mesh`` with the pallas backend the table
-    key and validation run on the *per-shard local shape* — the shard-mapped
-    kernel tiles its local block, so local M/N (and the VMEM/accumulator
-    bounds on the local K) are what a table entry must fit
-    (``repro.tune.space.local_shape``).
-
-    Resolution order:
-
-      1. no active tuning table  -> the paper's analytic rule + default tiles
-         (exactly the pre-``repro.tune`` behaviour);
-      2. active table with a measured entry for this (backend, bucketed
-         M/N/K, w) key -> the recorded winner, *validated* against the search
-         space's pruning rules (``max_exact_k`` int32-headroom, s8 digit
-         bounds, tile sanity) — an invalid entry is discarded, never run;
-      3. active table without an entry -> the cost-model prior from
-         :mod:`repro.core.complexity` ranks the pruned space.
-
-    ``pin_numerics`` (the default, used by every model-facing path)
-    guarantees the returned plan is numerics-identical to the analytic rule:
-    a table may swap variant/depth only inside the same
-    :func:`numerics_fingerprint` class (e.g. between exact-int32 variants);
-    otherwise only tile sizes are adopted — and on the fp32 Pallas path tiles
-    are adopted only when they imply the same zero-padding, since padded-K
-    fp32 correction terms round differently.  Tuning therefore never changes
-    ``quantized_matmul`` results, only how fast they are computed.
-    """
-    plan = _select_plan_impl(shape, w, m=m, backend=backend, exact=exact,
-                             table=table, pin_numerics=pin_numerics,
-                             context=context)
-    if obs_metrics.enabled():
-        _PLANS_SELECTED.inc(plan.variant, plan.backend,
-                            "x".join(str(d) for d in _bucket_cached(shape)),
-                            plan.source)
-    return plan
-
-
-@functools.lru_cache(maxsize=4096)
-def _bucket_cached(shape: Tuple[int, int, int]) -> Tuple[int, int, int]:
-    from repro.tune.space import bucket_shape   # lazy: core must not
-    return bucket_shape(shape)                  # hard-depend on tune
-
-
-def _select_plan_impl(shape: Tuple[int, int, int], w: int, *, m: int = 8,
-                      backend: str = "xla", exact: bool = False,
-                      table=None, pin_numerics: bool = True,
-                      context=None) -> ExecPlan:
-    if context is not None:
-        backend = context.backend
-        if table is None and context.tuning_table is not None:
-            table = context.resolve_table()
-        if context.mesh is not None and backend == "pallas":
-            # The shard-mapped kernel runs on the local block: key the
-            # table and validate bounds on the per-shard shape.
-            shape = context.local_gemm_shape(shape)
-    base = analytic_plan(w, m, backend=backend, exact=exact)
-    if table is None:
-        from repro.tune import table as tune_table   # lazy: core must not
-        table = tune_table.get_active_table()        # hard-depend on tune
-    if table is None:
-        return base
-    from repro.tune import space as tune_space
-    entry = table.lookup(backend, shape, w, m)
-    source = "table"
-    if entry is None:
-        entry = _prior_plan_cached(tune_space.bucket_shape(shape), w, m,
-                                   backend, exact)
-        source = "prior"
-    if entry is None:
-        return base
-    entry = replace(entry, w=w, m=m, backend=backend, source=source)
-    if tune_space.validate(entry, shape) is not None:
-        return base                      # never run a candidate that fails
-    if not pin_numerics:
-        return entry
-    if (numerics_fingerprint(entry) == numerics_fingerprint(base)
-            and _k_padding_matches(shape, base, entry)):
-        return entry
-    # Numerics differ (or the fp32-Pallas K padding would change): adopt
-    # tiles only, and only when the entry actually measured tiles — an
-    # xla_ref / ffip / xla-backend winner's recorded tiles are meaningless
-    # defaults, so keep the analytic plan wholesale.
-    if entry.variant not in _TILED_VARIANTS \
-            or entry.backend != "pallas":
-        return base
-    if not _k_padding_matches(shape, base,
-                              replace(base, block_k=entry.block_k)):
-        return base
-    return replace(base, block_m=entry.block_m, block_n=entry.block_n,
-                   block_k=entry.block_k, source=source + "+tiles")
-
-
-@functools.lru_cache(maxsize=4096)
-def _prior_plan_cached(bucket: Tuple[int, int, int], w: int, m: int,
-                       backend: str, exact: bool) -> Optional[ExecPlan]:
-    """Memoized cost-model prior per bucketed key: a table miss would
-    otherwise enumerate + rank the full candidate space at trace time for
-    every GEMM call site.  Keyed on the bucketed shape (the same key the
-    table uses); the returned plan is still re-validated against the real
-    runtime shape in select_plan.  Safe across table swaps — the prior
-    doesn't depend on table contents."""
-    from repro.tune import space as tune_space
-    return tune_space.prior_plan(bucket, w, m=m, backend=backend,
-                                 exact=exact)
-
-
-def _k_padding_matches(shape, base: ExecPlan, entry: ExecPlan) -> bool:
-    """On the fp32-combine Pallas path the result depends on the *padded*
-    contraction length: zero-padded K rows contribute centered digit planes
-    and the ``z*z*kp`` correction term, which cancel exactly in real
-    arithmetic but round differently in fp32 once accumulators pass 2**24.
-    Bit-identity with the analytic default therefore requires the same
-    padded K.  M/N padding is irrelevant (padded rows/cols are sliced away
-    and never enter retained outputs), and exact-int plans equal the true
-    product for any padding."""
-    if entry.is_exact_int or entry.backend != "pallas":
-        return True
-    k = shape[1]
-    return _padded(k, base.block_k) == _padded(k, entry.block_k)

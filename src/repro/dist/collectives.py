@@ -84,7 +84,7 @@ def _prep_rhs(w: Array, w_bits: Optional[int]):
     """Quantize the loop-invariant RHS once, outside the ring loop."""
     if w_bits is None:
         return w.astype(jnp.float32), None
-    from repro.kernels.ops import quantize_symmetric
+    from repro.quant.quantize import quantize_symmetric
 
     return quantize_symmetric(w, w_bits)
 
@@ -94,17 +94,15 @@ def _shard_matmul(a: Array, qb: Array, sb, w_bits: Optional[int],
     """One shard-chunk GEMM; integer path when a bitwidth is supplied.
 
     ``context`` (an :class:`repro.core.context.ExecContext`) picks the
-    backend for the chunk GEMMs.  Its mesh is stripped before the call: the
+    backend for the chunk GEMMs.  ``int_gemm`` reads only its backend: the
     ring already runs inside its own ``shard_map``, so each chunk is a
-    single-shard GEMM — re-entering :mod:`repro.dist.shard_gemm` from here
-    would nest shard_maps.
+    single-shard GEMM.
     """
     if w_bits is None:
         return jnp.dot(a.astype(jnp.float32), qb)
-    from repro.kernels.ops import int_gemm, quantize_symmetric
+    from repro.kernels.ops import int_gemm
+    from repro.quant.quantize import quantize_symmetric
 
-    if context is not None and context.mesh is not None:
-        context = context.replace(mesh=None)
     qa, sa = quantize_symmetric(a, w_bits)
     return int_gemm(qa, qb, w=w_bits, context=context) * sa * sb
 

@@ -19,28 +19,22 @@ Layout (capability negotiation, :func:`negotiate`):
     kernel launch and the zero-point correction runs per-shard *before* any
     collective, which is what keeps the contract exact.
 
-An explicit K-sharded spec (``GemmShardSpec(k_axes=...)``) is also executed
-— each shard's int32 partial product is ``psum``-combined — but only for
-exact-int plans, where integer partial sums equal the true product;
-:func:`negotiate` never proposes it (fp32-combine partials would change
-rounding; see ``numerics_fingerprint``).
+K is never sharded: fp32-combine partial sums would round differently.
 
-Fallback contract: when no mesh axis divides the GEMM (or the *local* K
-fails the kernel's ``max_exact_k`` / digit-accumulator / VMEM bounds), the
+Fallback contract: when no mesh axis divides the GEMM (or the kernel's plan,
+:func:`repro.kernels.fused_gemm.plan_for`, declines the *local* shape), the
 caller downgrades that GEMM to the XLA backend with a logged reason —
-capability negotiation, not a hard error (the old ``serve/engine.py``
-mesh-rejection is gone).
+capability negotiation, not a hard error.
 """
 from __future__ import annotations
 
 import logging
-from dataclasses import replace
+from dataclasses import dataclass
 from typing import Optional, Tuple
 
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.dispatch import ExecPlan, GemmShardSpec
 from repro.dist import sharding as dist_sharding
 from repro.obs import metrics as obs_metrics
 
@@ -62,6 +56,17 @@ _FALLBACKS = obs_metrics.counter(
     labels=("shape", "w", "reason"))
 
 
+@dataclass(frozen=True)
+class GemmShardSpec:
+    """How one GEMM's M and N dims (or a grouped GEMM's expert dim) map
+    onto mesh axes.  Each shard runs the kernel on its local block with the
+    full K, so values are bit-identical to the unsharded kernel."""
+
+    m_axes: Tuple[str, ...] = ()
+    n_axes: Tuple[str, ...] = ()
+    e_axes: Tuple[str, ...] = ()   # expert/group dim of grouped GEMMs
+
+
 def _axis_size(mesh: Mesh, axes: Tuple[str, ...]) -> int:
     size = 1
     for a in axes:
@@ -77,10 +82,9 @@ def _axis_entry(axes: Tuple[str, ...]):
 
 
 def local_shape(shape: Shape, spec: GemmShardSpec, mesh: Mesh) -> Shape:
-    """Per-shard (M, K, N) under ``spec`` on ``mesh``."""
+    """Per-shard (M, K, N) under ``spec`` on ``mesh`` (K stays whole)."""
     M, K, N = shape
-    return (M // _axis_size(mesh, spec.m_axes),
-            K // _axis_size(mesh, spec.k_axes),
+    return (M // _axis_size(mesh, spec.m_axes), K,
             N // _axis_size(mesh, spec.n_axes))
 
 
@@ -142,13 +146,8 @@ def shard_dense_gemm(fn, mesh: Mesh, spec: GemmShardSpec):
 
     ``qx``: (M, K); ``qw``: (K, N); ``sx``: (M, 1); ``sw``: (1, N); the
     returned callable takes the global operands and computes the global
-    (M, N) output with each shard running ``fn`` on its local block.  K must
-    be replicated in ``spec`` (fp32 bit-identity; use
-    :func:`sharded_run_plan` for exact-int split-K).
+    (M, N) output with each shard running ``fn`` on its local block.
     """
-    if spec.k_axes:
-        raise ValueError("dense dequant GEMM requires replicated K "
-                         "(fp32 bit-identity); got k_axes=%r" % (spec.k_axes,))
     ms, ns = _axis_entry(spec.m_axes), _axis_entry(spec.n_axes)
     return jax.shard_map(
         fn, mesh=mesh,
@@ -179,82 +178,3 @@ def shard_grouped_gemm(fn, mesh: Mesh, spec: GemmShardSpec,
     if counts is None:
         return f
     return lambda qx, qw, sx, sw: f(qx, qw, sx, sw, counts)
-
-
-def sharded_run_plan(a: Array, b: Array, *, plan: ExecPlan, mesh: Mesh,
-                     interpret: Optional[bool] = None,
-                     use_ref_kernels: bool = False) -> Array:
-    """Shard-mapped :func:`repro.kernels.ops.run_plan` on (M, K) x (K, N).
-
-    Uses ``plan.shard`` when set, else negotiates M/N axes.  Covers both the
-    fused kernel and the staged Pallas fallback variants — whatever the plan
-    routes to runs per-shard.  K-sharded specs are executed as int32 partial
-    products ``psum``-combined over the K axes (exact-int plans only: the
-    integer partials sum to the true product, so this composes the paper's
-    kernel with the mesh collectives without moving a bit).
-    """
-    from repro.kernels import ops   # lazy: ops -> dispatch -> (tune) cycle
-
-    spec = plan.shard
-    if spec is None:
-        spec, reason = negotiate((a.shape[0], a.shape[1], b.shape[1]), mesh)
-        if spec is None:
-            raise ValueError(f"cannot shard GEMM on mesh {mesh}: {reason}")
-    local_plan = replace(plan, shard=None)
-    if spec.k_axes and not local_plan.is_exact_int:
-        raise ValueError(
-            "K-sharded execution is exact-int only (fp32 partial sums "
-            f"change rounding); plan {local_plan.variant!r} is fp32-combine")
-    ms, ns, ks = (_axis_entry(spec.m_axes), _axis_entry(spec.n_axes),
-                  _axis_entry(spec.k_axes))
-
-    def local_fn(al, bl):
-        out = ops.run_plan(al, bl, plan=local_plan, interpret=interpret,
-                           use_ref_kernels=use_ref_kernels)
-        if spec.k_axes:
-            out = jax.lax.psum(out, spec.k_axes)
-        return out
-
-    f = jax.shard_map(local_fn, mesh=mesh,
-                      in_specs=(P(ms, ks), P(ks, ns)),
-                      out_specs=P(ms, ns), check_vma=False)
-    return f(a, b)
-
-
-def plan_local_bounds_ok(plan: ExecPlan, lshape: Shape, w: int,
-                         m: int) -> Tuple[bool, str]:
-    """Check the kernel's correctness bounds on the per-shard LOCAL shape.
-
-    Mirrors the unsharded checks in quant/qmatmul._fused_pallas, evaluated
-    on the local K (identical here since negotiation replicates K, but the
-    seam is explicit so K-sharded callers and future layouts stay honest) —
-    plus the per-shard VMEM accounting from :mod:`repro.tune.space`.
-    """
-    from repro.core.kmm import max_exact_k
-    from repro.core.strassen import STRASSEN_VARIANTS
-    from repro.tune import space as tune_space
-
-    _, k_local, _ = lshape
-    if plan.variant in STRASSEN_VARIANTS:
-        # Strassen's pre-adds and per-product accumulation must stay exact
-        # on the shard's LOCAL block: re-run the full composed-bound
-        # validation (tile split, (w+1)-bit sub-plan windows, sub tile
-        # sanity and VMEM on the local half dims) rather than mirroring
-        # its pieces here.
-        reason = tune_space.validate(plan, lshape)
-        if reason is not None:
-            return False, f"strassen bounds on local shape {lshape}: {reason}"
-        return True, ""
-    if plan.is_exact_int and max_exact_k(w) < k_local:
-        return False, (f"local K={k_local} > max_exact_k({w})="
-                       f"{max_exact_k(w)}")
-    kp = -(-k_local // plan.block_k) * plan.block_k
-    bound = tune_space.plan_accum_k_bound(plan)
-    if bound is not None and kp > bound:
-        return False, (f"local padded K={kp} > accum bound {bound} for "
-                       f"{plan.variant!r} depth={plan.depth} (w={w})")
-    vmem = tune_space.vmem_footprint(plan)
-    if vmem > tune_space.VMEM_BUDGET:
-        return False, (f"per-shard VMEM footprint {vmem} > "
-                       f"{tune_space.VMEM_BUDGET}")
-    return True, ""

@@ -1,16 +1,14 @@
 """Pallas TPU kernels (validated in interpret mode on CPU; see DESIGN.md §2).
 
-fused_gemm — single-pass KMM2/MM1 GEMM: in-kernel digit split, zero-point
-            correction and dequant epilogue in one pallas_call (the
-            production pallas route, DESIGN.md §11; grouped MoE variant).
-kmm_gemm  — KMM2 integer GEMM on pre-split planes: 3 digit-plane MXU
-            passes + Algorithm-5 two-level accumulation (the paper's
-            Fig. 8 architecture); staged fallback + fused-kernel oracle.
-mm2_gemm  — conventional 4-pass baseline (Fig. 3).
-mm1_gemm  — single-pass int8 baseline (Fig. 7).
+fused_gemm — single-pass MM1/KMM2/MM2/depth-2 KMM GEMM: in-kernel digit
+            split, zero-point correction and dequant epilogue in one
+            pallas_call, with its own plan (``plan_for``: the paper's mode,
+            tiles, combine, bounds); grouped and ragged MoE variants
+            (DESIGN.md §11).
 wkv_gemm  — RWKV6 recurrence with state resident in VMEM.
-ffip      — FFIP reference + why it has no MXU analogue.
-ops       — dispatching wrapper (digit planes, zero-point correction).
-ref       — pure-jnp oracles.
+ops       — ``int_gemm``: the paper's mode rule on the fused kernel
+            (pallas) or the digit recursion of repro.core.kmm (xla).
+ref       — tests' references: the int64 oracle and the jnp mirror of
+            the fused kernel.
 """
 from repro.kernels.ops import int_gemm, int_gemm_jit

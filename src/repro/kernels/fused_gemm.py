@@ -2,12 +2,8 @@
 
 The paper's KMM hardware (Figs. 8-9) wins because the digit pre-adders, the
 digit-plane multipliers and the post-adder combine live in *one* pipeline
-with no intermediate memory round-trips.  The staged Pallas path in
-:mod:`repro.kernels.ops` approximates that with ~6 HBM passes: ``_planes``
-materializes plane arrays, ``kmm2_gemm_planes`` reads them back, and the
-Section IV-D zero-point correction plus dequant each cost another
-array-sized pass.  This kernel is the faithful mapping: ONE ``pallas_call``
-that
+with no intermediate memory round-trips.  This kernel is that mapping: ONE
+``pallas_call`` that
 
   * reads the **original** integer operands (narrowest carrier: int8 for
     ``w <= m``, int16 up to ``w <= 16``, int32 above) — no pre-split planes
@@ -37,12 +33,17 @@ that
     fp32/bf16), so the quantized model path is 2 operand reads + 1 output
     write.
 
-Numerics are pinned to the staged path bit-for-bit (asserted across the
-pruned tune space by ``tests/test_fused_gemm.py`` / ``tests/test_tune.py``):
-the digit products and row/col sums are exact int32 regardless of tiling,
-and the fp32 combine applies the identical operation sequence as the staged
-kernels at every level, so interpret-mode CI can gate the fused kernel
-against the pure-jnp staged mirror with ``np.array_equal``.
+Numerics are pinned to the pure-jnp mirror
+:func:`repro.kernels.ref.ref_fused_gemm` bit for bit: the digit products and
+row/col sums are exact int32 regardless of tiling, and the fp32 combine
+applies the mirror's operation sequence at every level, so interpret-mode
+tests gate the kernel against the mirror with ``np.array_equal`` (and the
+exact int32 class against the int64 oracle).
+
+:func:`plan_for` is the kernel's own plan: the paper's mode for the width
+(:func:`repro.core.dispatch.select_mode`), the tiles, the combine precision
+and the correctness bounds (int32 headroom, digit-accumulator and
+zero-point-sum headroom, VMEM) in one place.
 
 ``fused_gemm_grouped`` adds a leading expert/group grid axis so MoE expert
 GEMMs ((E, C, K) x (E, K, N)) run as one kernel launch instead of an XLA
@@ -55,14 +56,15 @@ row skip their MXU passes entirely.
 from __future__ import annotations
 
 import functools
-from dataclasses import replace
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.dispatch import Mode, select_mode
+from repro.core.kmm import max_exact_k
 from repro.obs import metrics as obs_metrics
 
 Array = jax.Array
@@ -74,10 +76,9 @@ _DIGIT_DOTS = obs_metrics.counter(
     "fused-kernel launches traced, by mode and digit-dot path",
     labels=("mode", "path"))
 
-# Kernel modes (digit layouts).  "auto" resolves to the paper's default for
-# the width: mm1 (w <= m) or kmm2 (above).  mm2 and kmm4 are explicit
-# because they are *alternatives* inside overlapping width windows (the
-# dispatch/tuning layer owns the choice, not the kernel).
+# Kernel modes (digit layouts).  "auto" resolves to mm1 (w <= m) or kmm2
+# (above).  mm2 and kmm4 are valid inside overlapping width windows;
+# :func:`plan_for` picks the paper's one per width.
 MODES = ("mm1", "kmm2", "mm2", "kmm4")
 
 
@@ -194,9 +195,13 @@ def dot_path(mode: str, w: int, block_k: int, interpret: bool) -> str:
 
 
 # Largest tile edge of the int8 path: (512, 512, 512) is 5.25 MB of
-# tune.space.vmem_footprint for kmm2; (512, 1024, 512) overflows v5e's
-# 16 MB of scoped VMEM.
+# vmem_footprint for kmm2; (512, 1024, 512) overflows v5e's 16 MB of scoped
+# VMEM.
 _INT8_TILE = 512
+# Tiles outside the int8 path, each clamped to the pow2 cover of its dim.
+DEFAULT_TILES = (128, 128, 256)
+# Per-core VMEM budget for one grid step's tiles, accumulators and scratch.
+VMEM_BUDGET = 12 * 1024 * 1024
 
 
 def pow2_cover(n: int, lo: int = 8) -> int:
@@ -207,28 +212,37 @@ def pow2_cover(n: int, lo: int = 8) -> int:
     return v
 
 
+def vmem_footprint(mode: str, w: int, block_m: int, block_n: int,
+                   block_k: int) -> int:
+    """Per-grid-step VMEM bytes of a launch: the operand tiles in their
+    carrier (:func:`_carrier`), the mode's int32 digit accumulators plus
+    the output tile, and the zero-point and dequant scale vectors."""
+    opd = jnp.dtype(_carrier(mode, w)).itemsize
+    return (opd * (block_m * block_k + block_k * block_n)
+            + (_N_ACC[mode] + 1) * block_m * block_n * 4
+            + 4 * 2 * (block_m + block_n))
+
+
 def int8_tiles(shape, mode: str, w: int, m: int = 8
                ) -> Optional[Tuple[int, int, int]]:
     """Tiles (block_m, block_n, block_k) for an (M, K, N) GEMM whose digit
     products run on the int8 path, or None where ``(mode, w)`` is not on it
-    (mm1 and the widths :func:`int8_digits` refuses keep the analytic
-    clamp).  Int8 passes are cheap enough that the per-step cost and the
-    in-register split of each tile set the rate, so tiles grow to 512:
+    (mm1 and the widths :func:`int8_digits` refuses keep the pow2 clamp of
+    ``DEFAULT_TILES``).  Int8 passes are cheap enough that the per-step
+    cost and the in-register split of each tile set the rate, so tiles grow
+    to 512:
 
       * block_m: pow2 cover of M, at most 512;
       * block_n: of 512/256/128 (at most the pow2 cover of N), the one
         that pads N least, the larger on a tie (13824 = 27 x 512,
         1280 = 5 x 256);
       * block_k: 512 only where it pads K to the same length as the
-        analytic 256 clamp (the fp32 combine's correction reads padded K,
-        so another padding is another value), else that clamp.
+        256 clamp (the fp32 combine's correction reads padded K, so
+        another padding is another value), else that clamp.
 
-    A choice over ``tune.space.VMEM_BUDGET`` halves block_m until it fits
-    (kmm4 above w = 16, whose int32 carriers and 9 accumulators need it).
+    A choice over ``VMEM_BUDGET`` halves block_m until it fits (kmm4 above
+    w = 16, whose int32 carriers and 9 accumulators need it).
     """
-    from repro.core.dispatch import DEFAULT_TILES, ExecPlan
-    from repro.tune.space import VMEM_BUDGET, vmem_footprint
-
     mode = _resolve_mode(mode, w, m)
     if mode == "mm1" or not int8_digits(mode, w):
         return None
@@ -244,12 +258,98 @@ def int8_tiles(shape, mode: str, w: int, m: int = 8
     kp = -(-k_dim // bk) * bk
     if bk == DEFAULT_TILES[2] and kp % _INT8_TILE == 0:
         bk = _INT8_TILE
-    plan = ExecPlan("fused_mm2" if mode == "mm2" else "fused", w, m=m,
-                    backend="pallas", block_m=bm, block_n=bn, block_k=bk,
-                    depth=2 if mode == "kmm4" else 1)
-    while vmem_footprint(plan) > VMEM_BUDGET and plan.block_m > 8:
-        plan = replace(plan, block_m=plan.block_m // 2)
-    return plan.block_m, plan.block_n, plan.block_k
+    while vmem_footprint(mode, w, bm, bn, bk) > VMEM_BUDGET and bm > 8:
+        bm //= 2
+    return bm, bn, bk
+
+
+class FusedPlan(NamedTuple):
+    """How :func:`plan_for` runs one GEMM on the kernel."""
+
+    mode: str                 # one of MODES, never "auto"
+    block_m: int
+    block_n: int
+    block_k: int
+    combine_int32: bool       # int32 post-adder (exact) vs fp32
+
+    @property
+    def tiles(self) -> Tuple[int, int, int]:
+        return self.block_m, self.block_n, self.block_k
+
+
+def kernel_mode(w: int, m: int = 8) -> Optional[str]:
+    """The kernel mode of the paper's rule for width ``w`` (Fig. 10 via
+    :func:`repro.core.dispatch.select_mode`): mm1, kmm2, mm2, or kmm4 where
+    the rule needs two KMM levels.  None for deeper recursion, which the
+    kernel does not run."""
+    plan = select_mode(w, m)
+    if plan.mode is Mode.MM1:
+        return "mm1"
+    if plan.mode is Mode.MM2:
+        return "mm2"
+    return {1: "kmm2", 2: "kmm4"}.get(plan.recursion)
+
+
+def accum_k_bound(mode: str, w: int) -> Optional[int]:
+    """Largest padded K for which every int32 digit accumulator of the mode
+    stays exact; None for mm1, whose one accumulator holds the product and
+    is bounded by ``max_exact_k`` instead.  KMM2's pre-adder reaches 2**h,
+    so K reaches 2**(29 - w); MM2 has no pre-adder and kmm4's leaves are
+    about quarter-width (:func:`leaf_mag_bits`), so theirs reach
+    2**(30 - 2 * bits), one guard bit under the int32 edge."""
+    if mode == "mm1":
+        return None
+    head = 31 - w - 2 if mode == "kmm2" else 30 - 2 * leaf_mag_bits(mode, w)
+    return 1 << head if head > 0 else 1
+
+
+def zero_point_sums_fit(w: int, k_dim: int, kp: int) -> bool:
+    """The split modes accumulate rowsum(Abar) and colsum(Bbar) in int32
+    over the padded K, where every real entry lies in
+    [-2^(w-1) - z, 2^(w-1) - 1 - z] and every padded one is -z.  The fp32
+    combine reads those sums as values, so they must not wrap:
+    ``K * 2^(w-1) + Kp * z <= 2^31``.  Binding from w = 16 up (kmm4's
+    widths), where the digit accumulators reach far deeper K."""
+    z = 1 << (-(-w // 2) - 1)
+    return k_dim * (1 << (w - 1)) + kp * z <= 1 << 31
+
+
+def plan_for(shape, w: int, m: int = 8, exact: bool = False
+             ) -> Optional[FusedPlan]:
+    """The kernel's plan for an (M, K, N) GEMM at width ``w``, or None
+    outside the kernel's bounds (the caller falls back to XLA).
+
+    The mode is :func:`kernel_mode`'s; the tiles are :func:`int8_tiles`'
+    where the digit products run on the int8 path, else ``DEFAULT_TILES``
+    clamped to the pow2 cover of each dim (M/N padding is sliced away; the
+    K clamp fixes the fp32 class's padded K as a function of K alone).
+    The combine is int32 for ``exact`` and for mm1 (one int8 pass, exact by
+    construction), fp32 otherwise.  None when the mode needs depth >= 3,
+    when an int32 combine's product can overflow (``max_exact_k``), when
+    the padded K passes :func:`accum_k_bound` or the zero-point sums'
+    int32 range (:func:`zero_point_sums_fit`), or when the tiles pass
+    ``VMEM_BUDGET``.
+    """
+    mode = kernel_mode(w, m)
+    if mode is None:
+        return None
+    m_dim, k_dim, n_dim = shape
+    tiles = int8_tiles(shape, mode, w, m) or (
+        min(DEFAULT_TILES[0], pow2_cover(m_dim)),
+        min(DEFAULT_TILES[1], pow2_cover(n_dim)),
+        min(DEFAULT_TILES[2], pow2_cover(k_dim)))
+    plan = FusedPlan(mode, *tiles, combine_int32=exact or mode == "mm1")
+    if plan.combine_int32 and max_exact_k(w) < k_dim:
+        return None
+    kp = -(-k_dim // plan.block_k) * plan.block_k
+    bound = accum_k_bound(mode, w)
+    if bound is not None and kp > bound:
+        return None
+    if mode != "mm1" and not zero_point_sums_fit(w, k_dim, kp):
+        return None
+    if vmem_footprint(mode, w, *tiles) > VMEM_BUDGET:
+        return None
+    return plan
 
 
 def _fused_kernel(*refs, mode: str, h: int, h2: int, z: int, nk: int,
@@ -319,11 +419,10 @@ def _fused_kernel(*refs, mode: str, h: int, h2: int, z: int, nk: int,
             (acc0_ref,) = scratch
             acc0_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.int32)
             return
-        # VPU in-register digit split + centering (ops._planes, minus the
-        # HBM plane arrays).  The tile is widened to int32 first: the
-        # carrier stays narrow in HBM, but Mosaic has no int16 vector
-        # shift.  Digit values are unchanged, so the MXU products are the
-        # same exact int32 the staged plane kernels compute.
+        # VPU in-register digit split + centering (no HBM plane arrays).
+        # The tile is widened to int32 first: the carrier stays narrow in
+        # HBM, but Mosaic has no int16 vector shift.  Digit values are
+        # unchanged, so the MXU products are exact int32.
         a = a.astype(jnp.int32)
         b = b.astype(jnp.int32)
         mask = (1 << h) - 1
@@ -401,7 +500,7 @@ def _fused_kernel(*refs, mode: str, h: int, h2: int, z: int, nk: int,
 
 def _combine_kmm2(c1, cs, c0, h: int, combine_int32: bool):
     """KMM post-adder (Fig. 9): C = C1<<2h + (Cs-C1-C0)<<h + C0 — the exact
-    operation sequence of kmm2_gemm_planes / ref_kmm2_planes."""
+    operation sequence of ref_kmm2_planes."""
     if combine_int32:
         return (c1 << (2 * h)) + ((cs - c1 - c0) << h) + c0
     c1f = c1.astype(jnp.float32)
@@ -421,7 +520,7 @@ def _combine_kmm2_wide(c1, cs, c0, h: int, combine_int32: bool):
 
 def _combine_mm2(c1, c10, c01, c0, h: int, combine_int32: bool):
     """Conventional 4-product combine — the exact operation sequence of
-    mm2_gemm_planes / ref_mm2_planes (c10/c01 summed as fp32, not int)."""
+    ref_mm2_planes (c10/c01 summed as fp32, not int)."""
     if combine_int32:
         return (c1 << (2 * h)) + ((c10 + c01) << h) + c0
     mid = c10.astype(jnp.float32) + c01.astype(jnp.float32)
@@ -440,6 +539,14 @@ def _resolve_mode(mode: str, w: int, m: int) -> str:
     return mode
 
 
+def _carrier(mode: str, w: int):
+    """Narrowest operand carrier covering the window: int8 for mm1 (one MXU
+    pass, no split), int16 through w = 16 (KMM2/MM2 windows), int32 only
+    for the depth-2 widths."""
+    return (jnp.int8 if mode == "mm1" else
+            jnp.int16 if w <= 16 else jnp.int32)
+
+
 def _resolve(w: int, m: int, mode: str, dequant: bool, combine_int32: bool,
              out_dtype, interpret: Optional[bool]):
     if interpret is None:
@@ -449,12 +556,7 @@ def _resolve(w: int, m: int, mode: str, dequant: bool, combine_int32: bool,
     h = -(-w // 2) if split else 0
     h2 = -(-(h + 1) // 2) if mode == "kmm4" else 0
     z = (1 << (h - 1)) if split else 0
-    # Narrowest carrier covering the window: int8 for w <= m (one MXU pass,
-    # no split), int16 through w = 16 (KMM2/MM2 windows), int32 only for
-    # the deep-recursion widths — always at most half the staged wrapper's
-    # int32 plane-materialization traffic.
-    carrier = (jnp.int8 if not split else
-               jnp.int16 if w <= 16 else jnp.int32)
+    carrier = _carrier(mode, w)
     if out_dtype is None:
         out_dtype = (jnp.float32 if dequant else
                      jnp.int32 if (combine_int32 or not split) else
@@ -569,12 +671,12 @@ def fused_gemm(
     KMM2); ``"mm2"`` runs the conventional 4-pass boundary mode (valid
     through ``w <= 2m``); ``"kmm4"`` runs depth-2 KMM (4 digits, 9 passes)
     whose per-leaf int32 accumulators stay exact to far deeper K than the
-    single-level split (see ``tune.space.plan_accum_k_bound``).
+    single-level split (see :func:`accum_k_bound`).
 
     With ``sx`` (M, 1) / ``sw`` (1, N) fp32 scales the dequant epilogue
     ``out = acc * (sx * sw)`` runs in the same kernel (fp32, or ``out_dtype``
-    e.g. bf16) — bit-identical to the staged ``acc * (sx * sw)``
-    post-multiply.  Without scales the output is int32 for exact plans,
+    e.g. bf16) — bit-identical to the ``acc * (sx * sw)`` post-multiply of
+    the raw accumulator.  Without scales the output is int32 for exact plans,
     fp32 otherwise.
     """
     return _fused_call(a, b, sx, sw, None, grouped=False, w=w, m=m,

@@ -40,8 +40,6 @@ def main() -> int:
                     help="arrival rate in req/s (0: all at once)")
     ap.add_argument("--full-size", action="store_true",
                     help="full config (needs real accelerators)")
-    ap.add_argument("--tuning-table", default=None,
-                    help="repro.tune table JSON (DESIGN.md §10)")
     ap.add_argument("--backend", "--quant-backend", dest="backend",
                     default="xla", choices=["xla", "pallas"],
                     help="quantized-GEMM backend: 'pallas' serves through "
@@ -68,8 +66,8 @@ def main() -> int:
     from repro.serve.engine import Engine, Request
 
     enable_compile_cache()
-    # Observability is opt-in: enable before engine construction so plan
-    # selection / compile-time counters during warmup are captured too.
+    # Observability is opt-in: enable before engine construction so
+    # compile-time counters during warmup are captured too.
     if args.metrics_out:
         obs_metrics.enable()
     if args.trace_out:
@@ -79,8 +77,7 @@ def main() -> int:
     if args.mesh:
         from repro.launch.mesh import make_mesh
         mesh = make_mesh(tuple(int(x) for x in args.mesh.split("x")))
-    ctx = ExecContext(backend=args.backend, mesh=mesh,
-                      tuning_table=args.tuning_table)
+    ctx = ExecContext(backend=args.backend, mesh=mesh)
     cfg = get_config(args.arch, smoke=not args.full_size, quant=args.quant)
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
     engine = Engine(cfg, params, max_seq=args.max_seq, batch_size=args.batch,

@@ -31,8 +31,6 @@ def main() -> int:
     ap.add_argument("--resume", default="auto", choices=["auto", "none"])
     ap.add_argument("--max-restarts", type=int, default=0)
     ap.add_argument("--lr", type=float, default=3e-4)
-    ap.add_argument("--tuning-table", default=None,
-                    help="repro.tune table JSON (DESIGN.md §10)")
     args = ap.parse_args()
 
     logging.basicConfig(level=logging.INFO,
@@ -53,7 +51,6 @@ def main() -> int:
         ckpt_every=args.ckpt_every,
         ckpt_dir=args.ckpt_dir if args.resume == "auto" else None,
         optimizer=optim.AdamWConfig(lr=args.lr, total_steps=args.steps),
-        tuning_table=args.tuning_table,
     )
     data_cfg = DataConfig(
         vocab_size=cfg.vocab_size, seq_len=args.seq_len,

@@ -11,10 +11,10 @@ Two entry points: ``quantized_matmul`` for (..., K) @ (K, N) dense layers and
 ``quantized_matmul_batched`` for (E, C, K) @ (E, K, N) expert GEMMs.
 
 Execution is configured by an :class:`repro.core.context.ExecContext`
-(``context=`` kwarg): backend, mesh, tuning table and force_mode in one
-frozen bundle.  The legacy positional ``force_mode``/``backend`` kwargs keep
-working through a shim that emits ``DeprecationWarning`` (DESIGN.md §12
-migration table).
+(``context=`` kwarg): backend, mesh and force_mode in one frozen bundle.
+The legacy positional ``force_mode``/``backend`` kwargs keep working
+through a shim that emits ``DeprecationWarning`` (DESIGN.md §12 migration
+table).
 
 Backends.  ``backend="xla"`` (default) lowers to ordinary dot_generals (the
 digit recursion of :mod:`repro.core.kmm`) so pjit'd model code stays
@@ -28,27 +28,26 @@ single launch.  With ``context.mesh`` set, the kernel runs *shard-mapped*
 over the mesh (:mod:`repro.dist.shard_gemm`): M over the data axes, N over
 ``model``, K replicated — bit-identical to the unsharded kernel — with
 capability negotiation falling back to XLA (logged, per GEMM) when no mesh
-axis tiles the problem or the local-K bounds fail.  Plans resolve through
-the table-backed :func:`repro.core.dispatch.select_plan`; when the selected
-plan cannot run fused (e.g. w > 2m-2, digit-accumulator headroom, a table
-override, or ``force_mode``), the call falls back to the XLA path.
+axis tiles the problem or the local-K bounds fail.  The kernel's own plan
+(:func:`repro.kernels.fused_gemm.plan_for`) picks its mode, tiles and
+combine; where it declines (KMM recursion deeper than two levels, the
+int32 headroom of the product, the digit accumulators or the zero-point
+sums) or ``force_mode`` is set, the call falls back to the XLA path.
 """
 from __future__ import annotations
 
 import functools
 import math
-from dataclasses import replace
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.context import ExecContext, resolve_context
-from repro.core.dispatch import analytic_plan, select_plan
-from repro.core.kmm import kmm_n, max_exact_k, mm_n
-from repro.kernels import ops
+from repro.core.dispatch import Mode, select_mode
+from repro.core.kmm import kmm_n, mm_n
 from repro.kernels.fused_gemm import (fused_gemm, fused_gemm_grouped,
-                                      int8_tiles, pow2_cover)
+                                      kernel_mode, plan_for)
 from repro.obs import metrics as obs_metrics
 from repro.quant.quantize import quantize_symmetric
 
@@ -69,8 +68,8 @@ _WEIGHT_SOURCE = obs_metrics.counter(
     "repro_quant_weight_source_total",
     "quantized-GEMM weight operands by source (prequant or runtime)",
     labels=("source",))
-# Reasons the pallas route declined a GEMM (the table-independent XLA
-# fallbacks; mesh-negotiation fallbacks count in repro.dist's counter).
+# Reasons the pallas route declined a GEMM (the XLA fallbacks;
+# mesh-negotiation fallbacks count in repro.dist's counter).
 _PALLAS_FALLBACKS = obs_metrics.counter(
     "repro_pallas_fallback_total",
     "pallas-route declines by reason (GEMM fell back to XLA)",
@@ -98,121 +97,27 @@ def _quantize_weight(wmat: Array, w: int, axis: int) -> Tuple[Array, Array]:
     return qw, jax.lax.optimization_barrier(sw)
 
 
-def _dot_shape(qx: Array, qw: Array, dims) -> Tuple[int, int, int]:
-    """Flattened (M, K, N) of a dot_general (batch dims folded into M)."""
-    (lc, rc), (lb, rb) = dims
-    k = 1
-    for ax in lc:
-        k *= qx.shape[ax]
-    mm = 1
-    for ax in range(qx.ndim):
-        if ax not in lc:
-            mm *= qx.shape[ax]
-    n = 1
-    for ax in range(qw.ndim):
-        if ax not in rc and ax not in rb:
-            n *= qw.shape[ax]
-    return mm, k, n
-
-
 def _int_dot(qx: Array, qw: Array, w: int, m: int, dims,
              force_mode: str = "auto") -> Array:
-    """Integer GEMM on quantized values via the dispatched mode, fp32 out.
-
-    Mode selection goes through the table-backed
-    :func:`repro.core.dispatch.select_plan` (numerics-pinned: an installed
-    tuning table can never change the computed values here, only — on
-    backends where tiles matter — how they are computed), falling back to
-    the paper's analytic rule when no table is active.
-    """
-    eplan = select_plan(_dot_shape(qx, qw, dims), w, m=m, backend="xla")
-    if force_mode == "mm2" and w > m:
-        return mm_n(qx, qw, w=w, n=max(eplan.digits, 2),
-                    dimension_numbers=dims, combine_dtype=jnp.float32)
-    if eplan.is_exact_int:
-        # Every exact-class plan (mm1/xla_ref/ffip, int32-combine digit
-        # variants) computes the same integer; on arbitrary dot_general dims
-        # that integer is the fused int32 dot — identical to the analytic
-        # w <= m path, so table/prior substitutions cannot move a bit.
+    """Integer GEMM on quantized values via the paper's mode, fp32 out:
+    one int32 dot for MM1, the KMM/MM digit recursion above it (MM with
+    ``force_mode="mm2"``)."""
+    plan = select_mode(w, m)
+    if plan.mode is Mode.MM1:
         out = jax.lax.dot_general(qx, qw, dims,
                                   preferred_element_type=jnp.int32)
         return out.astype(jnp.float32)
-    # fp32 class: pin_numerics guarantees variant/depth match the analytic
-    # rule, so this runs exactly the paper's KMM2/MM2 digit recursion.
-    fn = kmm_n if eplan.variant == "kmm2" else mm_n
-    return fn(qx, qw, w=w, n=max(eplan.digits, 2), dimension_numbers=dims,
+    fn = kmm_n if plan.mode is Mode.KMM2 and force_mode != "mm2" else mm_n
+    return fn(qx, qw, w=w, n=plan.digits, dimension_numbers=dims,
               combine_dtype=jnp.float32)
-
-
-def _shrink_tiles(plan, shape):
-    """Clamp the analytic default tiles to the runtime shape (pow2 cover,
-    floor 8): serve-sized GEMMs (decode M = batch, prefill M = bucket)
-    would otherwise pad every operand up to 128x256 tiles.  M/N clamping
-    never affects values (padded rows/cols are sliced away and never enter
-    retained outputs); the K clamp fixes the fp32-class padded K as a pure
-    function of K, applied identically with or without a tuning table —
-    select_plan's padded-K guard only ever adopts table tiles whose padding
-    matches the un-clamped default, which the clamp preserves for every
-    K >= the default block_k.
-
-    Under a mesh this is called with the per-shard LOCAL shape: K is
-    replicated by the negotiated layout, so the K clamp (hence the fp32
-    padded K) is identical to the unsharded call — the M/N clamps adapt to
-    the local block, which never moves a bit.
-    """
-    return replace(plan,
-                   block_m=min(plan.block_m, pow2_cover(shape[0])),
-                   block_n=min(plan.block_n, pow2_cover(shape[2])),
-                   block_k=min(plan.block_k, pow2_cover(shape[1])))
-
-
-def _fused_plan_for(shape, w: int, m: int, context: Optional[ExecContext]):
-    """Resolve + tile the pallas plan for a (local) GEMM shape, and check
-    the kernel's correctness bounds.  Analytic plans whose digit products
-    run on the int8 path take the kernel's own tile rule
-    (:func:`repro.kernels.fused_gemm.int8_tiles`), the rest the clamp of
-    :func:`_shrink_tiles`; table plans keep their tiles.  Returns None on
-    any bound failure (the XLA fallback applies, table-independent)."""
-    from repro.tune.space import plan_accum_k_bound    # lazy: tune -> ops
-
-    m_dim, k_dim, n_dim = shape
-    table = context.resolve_table() if context is not None else None
-    plan = select_plan(shape, w, m=m, backend="pallas", table=table)
-    if plan.source == "analytic":
-        tiles = int8_tiles(shape, _fused_mode(plan), w, m)
-        if tiles is None:
-            plan = _shrink_tiles(plan, shape)
-        else:
-            bm, bn, bk = tiles
-            plan = replace(plan, block_m=bm, block_n=bn, block_k=bk)
-    # Correctness bounds (identical with or without a table; outside them
-    # the XLA fallback applies either way, keeping numerics table-free).
-    # The accumulator bound is plan-aware: MM2's pre-adder-free digits and
-    # depth-2's quarter-width leaves stretch the exact-K window well past
-    # the single-level KMM2 bound, and for the strassen variants it is the
-    # composed full-problem bound (tune.space.plan_accum_k_bound).
-    if plan.is_exact_int and max_exact_k(w) < k_dim:
-        return None
-    kp = -(-k_dim // plan.block_k) * plan.block_k
-    bound = plan_accum_k_bound(plan)
-    if bound is not None and kp > bound:
-        return None
-    return plan
-
-
-def _fused_mode(plan) -> str:
-    """The fused kernel's mode string for an ExecPlan routed to it."""
-    if plan.variant == "fused_mm2":
-        return "mm2"
-    return "kmm4" if plan.depth == 2 else "auto"
 
 
 def _ragged_row_mask(counts: Array, seg: int, c_dim: int) -> Array:
     """(E, C, 1) liveness of capacity-bucketed expert rows: row ``r`` is
     live iff ``r % seg < counts[e, r // seg]`` — the same predicate the
     ragged grouped kernel evaluates in-kernel, evaluated in jnp for the
-    XLA fallback and staged-redirect paths so the grouped ragged contract
-    (dead rows are exact zeros) holds on every backend."""
+    XLA route so the grouped ragged contract (dead rows are exact zeros)
+    holds on every backend."""
     rows = jnp.arange(c_dim, dtype=jnp.int32)
     seg_ids = rows // seg
     n_seg = counts.shape[-1]
@@ -220,6 +125,19 @@ def _ragged_row_mask(counts: Array, seg: int, c_dim: int) -> Array:
                      jnp.clip(seg_ids, 0, n_seg - 1), axis=-1)    # (E, C)
     live = (rows - seg_ids * seg < limit) & (seg_ids < n_seg)
     return live[..., None]
+
+
+def _run_fused(plan, qx, qw, sx, sw, w: int, m: int, dense: bool,
+               out_dtype, counts: Optional[Array] = None,
+               seg: Optional[int] = None) -> Array:
+    """The kernel at ``plan`` with the dequant epilogue: dense (M, K) x
+    (K, N) with (M, 1)/(1, N) scales, or grouped (E, C, K) x (E, K, N)."""
+    kw = dict(w=w, m=m, mode=plan.mode, block_m=plan.block_m,
+              block_n=plan.block_n, block_k=plan.block_k,
+              combine_int32=plan.combine_int32, out_dtype=out_dtype)
+    if dense:
+        return fused_gemm(qx, qw, sx, sw, **kw)
+    return fused_gemm_grouped(qx, qw, sx, sw, counts, seg=seg, **kw)
 
 
 def _sharded_pallas(qx: Array, qw: Array, sx: Array, sw: Array, w: int,
@@ -233,7 +151,7 @@ def _sharded_pallas(qx: Array, qw: Array, sx: Array, sw: Array, w: int,
     kernel), and with K replicated no collective touches the accumulators —
     sharded output is bit-identical to the unsharded fused output.  Returns
     None — the logged XLA fallback — when no mesh axis tiles the GEMM or
-    the plan fails its bounds on the local shape.
+    the kernel's plan declines the local shape.
     """
     from repro.dist import shard_gemm as sg
 
@@ -244,59 +162,23 @@ def _sharded_pallas(qx: Array, qw: Array, sx: Array, sw: Array, w: int,
         sg.log_fallback(shape, w, reason)
         return None
     lshape = sg.local_shape(shape, spec, mesh)
-    plan = _fused_plan_for(lshape, w, m, context)
+    plan = plan_for(lshape, w, m)
     if plan is None:
-        sg.log_fallback(shape, w, "local-K kernel bounds failed")
-        return None
-    ok, reason = sg.plan_local_bounds_ok(plan, lshape, w, m)
-    if not ok:
-        sg.log_fallback(shape, w, reason)
+        sg.log_fallback(shape, w, f"kernel bounds failed on local shape "
+                                  f"{lshape}")
         return None
     m_dim, k_dim, n_dim = shape
-    if plan.variant in ("fused", "fused_mm2"):
-        plan = replace(plan, epilogue="dequant", shard=spec)
-        mode = _fused_mode(plan)
 
-        def local_fused(qxl, qwl, sxl, swl, *cnt):
-            fn = fused_gemm if dense else fused_gemm_grouped
-            kw = {} if dense else {"counts": cnt[0] if cnt else None,
-                                   "seg": seg}
-            return fn(qxl, qwl, sxl, swl, w=w, m=m, mode=mode,
-                      block_m=plan.block_m, block_n=plan.block_n,
-                      block_k=plan.block_k,
-                      combine_int32=plan.combine_int32,
-                      out_dtype=out_dtype, **kw)
+    def local_fused(qxl, qwl, sxl, swl, *cnt):
+        return _run_fused(plan, qxl, qwl, sxl, swl, w, m, dense, out_dtype,
+                          counts=cnt[0] if cnt else None, seg=seg)
 
-        if dense:
-            f = sg.shard_dense_gemm(local_fused, mesh, spec)
-            out = f(qx.reshape(m_dim, k_dim), qw,
-                    sx.reshape(m_dim, 1), sw.reshape(1, n_dim))
-            return out.reshape(qx.shape[:-1] + (n_dim,))
-        return sg.shard_grouped_gemm(local_fused, mesh, spec,
-                                     counts=counts)(qx, qw, sx, sw)
-    # Table/prior redirect inside the pinned fingerprint class: run the
-    # staged plan shard-mapped through the production seam, dequant after.
-    qw = qw.astype(jnp.int32)           # staged kernels take int32 operands
-    plan = replace(plan, shard=spec)
     if dense:
-        acc = sg.sharded_run_plan(qx.reshape(m_dim, k_dim), qw, plan=plan,
-                                  mesh=mesh)
-        out = (acc.astype(jnp.float32)
-               * (sx.reshape(m_dim, 1) * sw.reshape(1, n_dim)))
-        return out.astype(out_dtype).reshape(qx.shape[:-1] + (n_dim,))
-    local_plan = replace(plan, shard=None)
-
-    def local_staged(qxl, qwl, sxl, swl, *cnt):
-        accs = [ops.run_plan(qxl[e], qwl[e], plan=local_plan)
-                for e in range(qxl.shape[0])]
-        acc = jnp.stack(accs).astype(jnp.float32)
-        out = (acc * (sxl * swl)).astype(out_dtype)
-        if cnt:
-            out = jnp.where(_ragged_row_mask(cnt[0], seg, out.shape[1]),
-                            out, jnp.zeros_like(out))
-        return out
-
-    return sg.shard_grouped_gemm(local_staged, mesh, spec,
+        f = sg.shard_dense_gemm(local_fused, mesh, spec)
+        out = f(qx.reshape(m_dim, k_dim), qw,
+                sx.reshape(m_dim, 1), sw.reshape(1, n_dim))
+        return out.reshape(qx.shape[:-1] + (n_dim,))
+    return sg.shard_grouped_gemm(local_fused, mesh, spec,
                                  counts=counts)(qx, qw, sx, sw)
 
 
@@ -304,21 +186,14 @@ def _fused_pallas(qx: Array, qw: Array, sx: Array, sw: Array, w: int, m: int,
                   dims, out_dtype, context: Optional[ExecContext] = None,
                   counts: Optional[Array] = None,
                   seg: Optional[int] = None) -> Optional[Array]:
-    """Run the GEMM + dequant epilogue on the Pallas backend.
+    """Run the GEMM + dequant epilogue on the fused Pallas kernel.
 
-    The selected plan is normally the fused single-pass kernel; a tuning
-    table may redirect to a staged Pallas plan *within the same numerics
-    fingerprint class* (select_plan pins it) — including the tile-level
-    strassen variants in the exact MM1-window class — in which case the
-    redirected plan runs through ``ops.run_plan`` with a post-multiply
-    dequant — bit-identical to the fused epilogue, so installing a table
-    can never move a bit of this backend's output.  Returns None — the XLA fallback — only for reasons that are
-    table-independent: unsupported dot_general dims, w outside the fused
-    windows (the analytic pallas rule is not "fused"), or the runtime shape
-    exceeding the kernel's correctness bounds (digit-accumulator / int32
-    headroom).  With ``context.mesh`` set the kernel runs shard-mapped
-    (:func:`_sharded_pallas`); capability-negotiation failures there also
-    return None, with a logged reason.
+    Returns None — the XLA fallback — for unsupported dot_general dims, for
+    widths the kernel does not run (KMM recursion deeper than two levels),
+    or where :func:`repro.kernels.fused_gemm.plan_for` declines the shape
+    (digit-accumulator / int32 headroom).  With ``context.mesh`` set the
+    kernel runs shard-mapped (:func:`_sharded_pallas`); capability-
+    negotiation failures there also return None, with a logged reason.
     """
     dense = qw.ndim == 2 and dims == (((qx.ndim - 1,), (0,)), ((), ()))
     batched = (qx.ndim == 3 and qw.ndim == 3
@@ -334,50 +209,24 @@ def _fused_pallas(qx: Array, qw: Array, sx: Array, sw: Array, w: int, m: int,
         _, m_dim, k_dim = qx.shape
         n_dim = qw.shape[2]
     shape = (m_dim, k_dim, n_dim)
-    if analytic_plan(w, m, backend="pallas").variant \
-            not in ("fused", "fused_mm2"):
+    if kernel_mode(w, m) is None:
         _PALLAS_FALLBACKS.inc("outside_fused_window")
         return None                     # recursion deeper than 2 levels
     if context is not None and context.mesh is not None \
             and not getattr(context.mesh, "empty", False):
         return _sharded_pallas(qx, qw, sx, sw, w, m, dense, shape,
                                out_dtype, context, counts=counts, seg=seg)
-    plan = _fused_plan_for(shape, w, m, context)
+    plan = plan_for(shape, w, m)
     if plan is None:
         _PALLAS_FALLBACKS.inc("kernel_bounds")
         return None
-    if plan.variant in ("fused", "fused_mm2"):
-        plan = replace(plan, epilogue="dequant")
-        mode = _fused_mode(plan)
-        if dense:
-            out = fused_gemm(
-                qx.reshape(m_dim, k_dim), qw,
-                sx.reshape(m_dim, 1), sw.reshape(1, n_dim),
-                w=w, m=m, mode=mode, block_m=plan.block_m,
-                block_n=plan.block_n, block_k=plan.block_k,
-                combine_int32=plan.combine_int32, out_dtype=out_dtype)
-            return out.reshape(qx.shape[:-1] + (n_dim,))
-        return fused_gemm_grouped(
-            qx, qw, sx, sw, counts, w=w, m=m, mode=mode, seg=seg,
-            block_m=plan.block_m, block_n=plan.block_n,
-            block_k=plan.block_k, combine_int32=plan.combine_int32,
-            out_dtype=out_dtype)
-    # Table/prior redirect inside the pinned fingerprint class: run the
-    # selected plan through the production seam and dequant afterwards.
-    qw = qw.astype(jnp.int32)           # staged kernels take int32 operands
     if dense:
-        acc = ops.run_plan(qx.reshape(m_dim, k_dim), qw, plan=plan)
-        out = (acc.astype(jnp.float32)
-               * (sx.reshape(m_dim, 1) * sw.reshape(1, n_dim)))
-        return out.astype(out_dtype).reshape(qx.shape[:-1] + (n_dim,))
-    accs = [ops.run_plan(qx[e], qw[e], plan=plan)
-            for e in range(qx.shape[0])]
-    acc = jnp.stack(accs).astype(jnp.float32)
-    out = (acc * (sx * sw)).astype(out_dtype)
-    if counts is not None:
-        out = jnp.where(_ragged_row_mask(counts, seg, out.shape[1]),
-                        out, jnp.zeros_like(out))
-    return out
+        out = _run_fused(plan, qx.reshape(m_dim, k_dim), qw,
+                         sx.reshape(m_dim, 1), sw.reshape(1, n_dim), w, m,
+                         dense, out_dtype)
+        return out.reshape(qx.shape[:-1] + (n_dim,))
+    return _run_fused(plan, qx, qw, sx, sw, w, m, dense, out_dtype,
+                      counts=counts, seg=seg)
 
 
 def _quant_gemm(qx: Array, qw: Array, sx: Array, sw: Array, w: int, m: int,
@@ -417,8 +266,7 @@ def _quant_gemm(qx: Array, qw: Array, sx: Array, sw: Array, w: int, m: int,
 # ---------------------------------------------------------------------------
 # custom_vjp cores (STE backward).  The public entry points below are plain
 # shims that resolve an ExecContext and call these; the context is a
-# hashable nondiff arg (its tuning table is excluded from eq/hash and is
-# installed around the traced call by the shim instead).
+# hashable nondiff arg.
 # ---------------------------------------------------------------------------
 
 
@@ -536,12 +384,11 @@ def quantized_matmul(x: Array, wmat: Array, w_bits: int, m: int = 8,
     """(..., K) @ (K, N) quantized to ``w_bits``; returns x.dtype.
 
     Pass ``context=`` (an :class:`~repro.core.context.ExecContext`) to pick
-    backend / mesh / tuning table / force_mode; the positional
+    backend / mesh / force_mode; the positional
     ``force_mode``/``backend`` kwargs are deprecated shims.
     """
     ctx = _ctx(context, force_mode, backend, "quantized_matmul")
-    with ctx.activate():
-        return _qmm_core(x, wmat, w_bits, m, ctx)
+    return _qmm_core(x, wmat, w_bits, m, ctx)
 
 
 def quantized_matmul_batched(x: Array, wmat: Array, w_bits: int,
@@ -567,12 +414,11 @@ def quantized_matmul_batched(x: Array, wmat: Array, w_bits: int,
     retrace.
     """
     ctx = _ctx(context, force_mode, backend, "quantized_matmul_batched")
-    with ctx.activate():
-        if counts is None:
-            return _qbmm_core(x, wmat, w_bits, m, ctx)
-        if seg is None or seg <= 0:
-            raise ValueError("ragged counts need a positive static seg")
-        return _qbmm_ragged_core(x, wmat, counts, w_bits, m, seg, ctx)
+    if counts is None:
+        return _qbmm_core(x, wmat, w_bits, m, ctx)
+    if seg is None or seg <= 0:
+        raise ValueError("ragged counts need a positive static seg")
+    return _qbmm_ragged_core(x, wmat, counts, w_bits, m, seg, ctx)
 
 
 def prequant_matmul(x: Array, wrec, w_bits: int, m: int = 8,
@@ -595,9 +441,8 @@ def prequant_matmul(x: Array, wrec, w_bits: int, m: int = 8,
         else (((x.ndim - 1,), (0,)), ((), ()))
     if counts is not None and not batched:
         raise ValueError("ragged counts require batched=True")
-    with ctx.activate():
-        return _quant_gemm(qx, wrec["q"], sx, wrec["scale"], w_bits, m, dims,
-                           ctx, x.dtype, counts=counts, seg=seg)
+    return _quant_gemm(qx, wrec["q"], sx, wrec["scale"], w_bits, m, dims,
+                       ctx, x.dtype, counts=counts, seg=seg)
 
 
 def _model_context(quant) -> ExecContext:

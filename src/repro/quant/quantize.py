@@ -1,12 +1,9 @@
 """The one symmetric quantizer every integer path shares.
 
-Three near-identical copies used to live in ``kernels/ops.quantize_symmetric``
-(keepdims only when an axis was given), ``quant/qmatmul._quantize``
-(keepdims always) and inline in ``quant/prequant.prequantize`` (plus a
-storage-dtype cast).  The fused-kernel dequant epilogue multiplies scales
-*inside* the GEMM kernel, so activation/weight scales must be produced by
-exactly one rounding recipe or the Pallas and XLA backends drift apart.
-This module is that recipe:
+The fused-kernel dequant epilogue multiplies scales *inside* the GEMM
+kernel, so activation/weight scales must be produced by exactly one rounding
+recipe or the Pallas and XLA backends drift apart.  This module is that
+recipe:
 
     qmax  = 2**(bits-1) - 1
     amax  = max(|x|) over ``axis`` (fp32)

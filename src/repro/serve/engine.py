@@ -18,7 +18,7 @@ cache and every jit.  It is now wiring between three seams:
     cold prefill.
   * :mod:`repro.serve.executor` — the compiled gather/compute/scatter entry
     points over the pool, riding the existing ``ExecContext`` execution
-    path (quantized KMM policy, optional mesh, tuning tables).
+    path (quantized KMM policy, optional mesh).
 
 Correctness on ragged prompts is unchanged from the dense-cache engine:
 prompts are right-padded to bucket widths with ``pad_mask``/``last_idx``
@@ -39,10 +39,9 @@ kv-heads over ``model``), and the executor's jits run under the mesh so
 GSPMD partitions them (DESIGN.md §4.3, §13).  With the pallas quant
 backend the mesh is *negotiated* per GEMM (:mod:`repro.dist.shard_gemm`).
 
-Execution policy (backend / tuning table / force_mode) is configured with
-``context=`` (an :class:`repro.core.context.ExecContext`); the legacy
-``quant_backend=`` / ``tuning_table=`` kwargs keep working behind a
-``DeprecationWarning`` (DESIGN.md §12).
+Execution policy (backend / force_mode) is configured with ``context=`` (an
+:class:`repro.core.context.ExecContext`); the legacy ``quant_backend=``
+kwarg keeps working behind a ``DeprecationWarning`` (DESIGN.md §12).
 """
 from __future__ import annotations
 
@@ -110,7 +109,6 @@ class Engine:
                  batch_size: int = 4, rng_seed: int = 0,
                  mesh: Optional[Mesh] = None,
                  prompt_buckets: Optional[Sequence[int]] = None,
-                 tuning_table: Optional[Any] = None,
                  quant_backend: Optional[str] = None,
                  context: Optional[ExecContext] = None,
                  page_size: Optional[int] = None,
@@ -126,7 +124,6 @@ class Engine:
         # folded into the context below.
         ctx = resolve_context(
             context, what="Engine", backend=quant_backend,
-            tuning_table=tuning_table,
             _defaults=ExecContext(
                 backend=getattr(cfg.quant, "backend", "xla"),
                 force_mode=getattr(cfg.quant, "force_mode", "auto")))
@@ -148,13 +145,6 @@ class Engine:
             log.info("serving with pallas quant backend under mesh %s: "
                      "GEMMs run shard-mapped where the mesh tiles them, "
                      "XLA otherwise (see repro.dist logs)", mesh)
-        if ctx.tuning_table is not None:
-            # Installs the PROCESS-GLOBAL registry before any jit below
-            # traces (jit caches keep the plans active at trace time).
-            # Tables are numerics-pinned: a table changes speed, never
-            # tokens (DESIGN.md §10).
-            from repro.tune import set_active_table
-            set_active_table(ctx.tuning_table)
         self.context = ctx
         self.cfg = cfg
         self._recurrent_layers = cfg.n_periods * sum(

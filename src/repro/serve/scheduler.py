@@ -41,11 +41,7 @@ _ADMITTED = obs_metrics.counter(
 
 def prompt_buckets_for(max_seq: int,
                        min_bucket: int = MIN_BUCKET) -> Tuple[int, ...]:
-    """Default prompt-bucket ladder: powers of two up to ``max_seq``.
-
-    Shared with ``python -m repro.tune --shapes serve`` so the tuner sweeps
-    exactly the prefill shapes the engine will execute.
-    """
+    """Default prompt-bucket ladder: powers of two up to ``max_seq``."""
     buckets = []
     b = min_bucket
     while b < max_seq:

@@ -37,14 +37,6 @@ class TrainConfig:
     seed: int = 0
     straggler_factor: float = 3.0   # watchdog: step > factor x median -> warn
     optimizer: optim.AdamWConfig = field(default_factory=optim.AdamWConfig)
-    # Execution context (repro.core.context.ExecContext); its tuning table
-    # is installed into the process-global registry before the train step
-    # traces (no table leaves the currently active one untouched; clear
-    # with repro.tune.set_active_table(None)).  Numerics-pinned: changes
-    # how quantized GEMMs run, never the loss values.
-    context: Optional[Any] = None
-    # Deprecated: table path — use context=ExecContext(tuning_table=...).
-    tuning_table: Optional[str] = None
 
 
 @dataclass
@@ -71,12 +63,6 @@ def run_training(cfg: ModelConfig, mesh: Mesh, tc: TrainConfig,
                  data_cfg: Optional[DataConfig] = None,
                  hooks: Optional[Dict[str, Callable]] = None) -> TrainResult:
     hooks = hooks or {}
-    from repro.core.context import resolve_context
-    ctx = resolve_context(tc.context, what="TrainConfig",
-                          tuning_table=tc.tuning_table or None)
-    if ctx.tuning_table is not None:
-        from repro.tune import set_active_table
-        set_active_table(ctx.tuning_table)
     data_cfg = data_cfg or DataConfig(
         vocab_size=cfg.vocab_size, seq_len=256, global_batch=8,
         frontend=cfg.frontend, frontend_dim=cfg.frontend_dim,

@@ -14,7 +14,6 @@ import numpy as np
 import pytest
 
 from repro.core.context import ExecContext, resolve_context
-from repro.core.dispatch import select_plan
 from repro.kernels import ops
 from repro.quant.qmatmul import (
     prequant_matmul, quantized_matmul, quantized_matmul_batched,
@@ -49,9 +48,8 @@ def test_context_validates_fields():
 
 def test_context_hashable_and_table_excluded_from_eq():
     a = ExecContext(backend="pallas")
-    b = ExecContext(backend="pallas", tuning_table="/some/table.json")
-    # tables are numerics-pinned: contexts differing only in table are
-    # interchangeable as jit static args / cache keys
+    b = ExecContext(backend="pallas")
+    # equal contexts are interchangeable as jit static args / cache keys
     assert a == b and hash(a) == hash(b)
     assert a != ExecContext(backend="xla")
     jax.jit(lambda x: x + 1, static_argnames=())  # smoke: hashability used
@@ -76,10 +74,10 @@ def test_resolve_context_legacy_folds_and_warns_once():
     with warnings.catch_warnings(record=True) as rec:
         warnings.simplefilter("always")
         ctx = resolve_context(None, what="thing", backend="pallas",
-                              tuning_table="tbl.json")
+                              force_mode="mm2")
     msg = _one_deprecation(rec)
-    assert "thing" in msg and "backend" in msg and "tuning_table" in msg
-    assert ctx.backend == "pallas" and ctx.tuning_table == "tbl.json"
+    assert "thing" in msg and "backend" in msg and "force_mode" in msg
+    assert ctx.backend == "pallas" and ctx.force_mode == "mm2"
 
 
 def test_resolve_context_rejects_mixed():
@@ -155,35 +153,6 @@ def test_int_gemm_context(operands):
     assert np.array_equal(np.asarray(via_ctx), np.asarray(via_kwarg))
 
 
-def test_select_plan_context(monkeypatch):
-    shape = (128, 1024, 128)
-    via_kwarg = select_plan(shape, 12, backend="pallas")
-    via_ctx = select_plan(shape, 12, context=ExecContext(backend="pallas"))
-    assert via_ctx == via_kwarg
-    # context backend wins over the legacy kwarg default
-    assert select_plan(shape, 12,
-                       context=ExecContext(backend="xla")).backend == "xla"
-
-
-def test_context_tuning_table_activate(tmp_path):
-    """context.tuning_table routes through select_plan without mutating the
-    global registry outside activate()."""
-    from repro.core.dispatch import ExecPlan
-    from repro.tune.table import TuningTable, get_active_table
-
-    table = TuningTable(device="cpu/test")
-    table.put("pallas", (128, 1024, 128), 12,
-              ExecPlan("fused", 12, backend="pallas", block_m=32,
-                       block_n=32, block_k=512))
-    ctx = ExecContext(backend="pallas", tuning_table=table)
-    plan = select_plan((128, 1024, 128), 12, context=ctx)
-    assert plan.source == "table" and plan.block_m == 32
-    assert get_active_table() is None   # registry untouched
-    with ctx.activate():
-        assert get_active_table() is table
-    assert get_active_table() is None
-
-
 # ---------------------------------------------------------------------------
 # Engine shim.
 # ---------------------------------------------------------------------------
@@ -216,20 +185,3 @@ def test_engine_shim_equivalence():
     assert toks_new == toks_old
     with pytest.raises(TypeError, match="not both"):
         run(quant_backend="pallas", context=ExecContext())
-
-
-def test_train_config_tuning_table_deprecated(tmp_path):
-    """TrainConfig.tuning_table folds into a context with a warning."""
-    from repro.train.loop import TrainConfig
-
-    tc = TrainConfig(tuning_table=None, context=None)
-    assert resolve_context(tc.context, what="TrainConfig",
-                           tuning_table=tc.tuning_table or None) \
-        == ExecContext()
-    tc2 = TrainConfig(tuning_table="tbl.json")
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        ctx = resolve_context(tc2.context, what="TrainConfig",
-                              tuning_table=tc2.tuning_table or None)
-    _one_deprecation(rec)
-    assert ctx.tuning_table == "tbl.json"

@@ -1,74 +1,83 @@
 """Fused single-pass kernel (kernels/fused_gemm.py): bit-identity against the
-staged Pallas path and the ref.py oracle across hostile tile/padding combos,
-the exact-int32 boundary, the grouped expert grid, and the dequant epilogue.
+pure-jnp mirror (ref.ref_fused_gemm) and the int64 oracle across hostile
+tile/padding combos and every width the kernel serves, the exact-int32
+boundary, the grouped expert grid, the dequant epilogue, and the kernel's
+own plan (plan_for) at the shapes the benchmark serves.
 """
 import functools
-from dataclasses import replace
+import importlib.util
+import json
+import sys
+from pathlib import Path
 
 import jax
 import numpy as np
 import pytest
 import jax.numpy as jnp
 
-from repro.core.dispatch import ExecPlan, analytic_plan, select_plan
 from repro.core.kmm import max_exact_k
 from repro.kernels import ops
 from repro.kernels.fused_gemm import (
-    _fused_call, digit_range, dot_path, fused_gemm, fused_gemm_grouped,
-    int8_digits, int8_tiles,
+    DEFAULT_TILES, VMEM_BUDGET, _fused_call, digit_range, dot_path,
+    fused_gemm, fused_gemm_grouped, int8_digits, int8_tiles, kernel_mode,
+    plan_for, pow2_cover, vmem_footprint, zero_point_sums_fit,
 )
-from repro.kernels.ref import ref_int_gemm_i64
+from repro.kernels.ref import ref_fused_gemm, ref_int_gemm_i64
 from repro.obs import metrics as obs_metrics
 from repro.quant.qmatmul import (
     prequant_matmul, quantized_matmul, quantized_matmul_batched,
 )
-from repro.tune import runner, space
 
 # Non-multiple M/N/K, 1-row/1-col extremes, K-padding that exercises the
 # z-correction on padded rows (split(0) = (0, -z) must cancel exactly).
 HOSTILE_SHAPES = [(33, 70, 17), (1, 64, 1), (130, 70, 50)]
 TILE_COMBOS = [(32, 32, 32), (64, 32, 64), (32, 64, 256)]
 
+# The mirror, jitted like the kernel wrapper.
+_mirror = jax.jit(ref_fused_gemm,
+                  static_argnames=("w", "mode", "block_k", "combine_int32"))
 
-def _staged_variant(w: int, m: int = 8) -> str:
-    return "mm1" if w <= m else "kmm2"
+
+def _operands(shape, w: int, seed: int = 0):
+    """Random signed w-bit operands for an (M, K) x (K, N) problem."""
+    m, k, n = shape
+    rng = np.random.default_rng(seed)
+    lim = 2 ** (w - 1)
+    a = rng.integers(-lim, lim, size=(m, k)).astype(np.int32)
+    b = rng.integers(-lim, lim, size=(k, n)).astype(np.int32)
+    return jnp.asarray(a), jnp.asarray(b)
 
 
-def _plans(w: int, tiles, combine_int32: bool):
+def _kernel_and_mirror(a, b, *, w, mode, tiles, combine_int32):
     bm, bn, bk = tiles
-    depth = 0 if w <= 8 else 1
-    fused = ExecPlan("fused", w, backend="pallas", block_m=bm, block_n=bn,
-                     block_k=bk, combine_int32=combine_int32, depth=depth)
-    staged = ExecPlan(_staged_variant(w), w, backend="pallas", block_m=bm,
-                      block_n=bn, block_k=bk, combine_int32=combine_int32,
-                      depth=depth)
-    return fused, staged
+    out = np.asarray(fused_gemm(a, b, w=w, mode=mode, block_m=bm,
+                                block_n=bn, block_k=bk,
+                                combine_int32=combine_int32))
+    mirror = np.asarray(_mirror(a, b, w=w, mode=mode, block_k=bk,
+                                combine_int32=combine_int32))
+    return out, mirror
 
 
 # ---------------------------------------------------------------------------
-# Satellite: bit-identity vs the staged path + the ref.py oracle.
+# Bit-identity vs the jnp mirror + the int64 oracle.
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("w", [4, 8, 12, 14])
 @pytest.mark.parametrize("mkn", HOSTILE_SHAPES)
-def test_fused_bit_identical_to_staged_and_mirror(w, mkn):
-    """Same tiles, same padding: the fused kernel must reproduce the staged
-    Pallas pipeline AND the pure-jnp staged mirror bit-for-bit — fp32
-    combine included (identical operation sequence, not a tolerance)."""
-    a, b = runner.make_operands(mkn, w, seed=w)
+def test_fused_bit_identical_to_mirror(w, mkn):
+    """Same padding: the fused kernel must reproduce the pure-jnp mirror
+    bit-for-bit — fp32 combine included (identical operation sequence, not
+    a tolerance) — and the int64 oracle in the exact MM1 window."""
+    a, b = _operands(mkn, w, seed=w)
     oracle = ref_int_gemm_i64(np.asarray(a), np.asarray(b))
+    mode = "mm1" if w <= 8 else "kmm2"
     for tiles in TILE_COMBOS:
-        fused, staged = _plans(w, tiles, combine_int32=w <= 8)
-        out = np.asarray(ops.run_plan_jit(a, b, fused))
-        np.testing.assert_array_equal(
-            out, np.asarray(ops.run_plan_jit(a, b, staged)),
-            err_msg=f"fused != staged at w={w} tiles={tiles}")
-        np.testing.assert_array_equal(
-            out, np.asarray(ops.run_plan_jit(a, b, fused,
-                                             use_ref_kernels=True)),
-            err_msg=f"fused != jnp mirror at w={w} tiles={tiles}")
-        if fused.is_exact_int:
+        out, mirror = _kernel_and_mirror(a, b, w=w, mode=mode, tiles=tiles,
+                                         combine_int32=w <= 8)
+        assert np.array_equal(out, mirror), \
+            f"fused != jnp mirror at w={w} tiles={tiles}"
+        if w <= 8:
             np.testing.assert_array_equal(out.astype(np.int64), oracle)
 
 
@@ -83,124 +92,83 @@ def _i64_oracle_atol(w: int, k: int) -> float:
 
 @pytest.mark.parametrize("w", [14, 15, 16])
 @pytest.mark.parametrize("mkn", HOSTILE_SHAPES)
-def test_fused_mm2_bit_identical_to_staged_and_mirror(w, mkn):
-    """The single-pass MM2 boundary mode (w = 2m-1, 2m): fused_mm2 must
-    reproduce the staged Pallas MM2 pipeline AND the pure-jnp mirror
-    bit-for-bit, and sit at the fp32 noise floor of the int64 oracle.
-    w=14 runs the 4-pass mode *inside* the KMM2 window — the mode is valid
-    anywhere in (m, 2m], not just on the boundary."""
-    a, b = runner.make_operands(mkn, w, seed=w)
+def test_fused_mm2_bit_identical_to_mirror(w, mkn):
+    """The single-pass MM2 boundary mode (w = 2m-1, 2m): the kernel must
+    reproduce the pure-jnp mirror bit-for-bit and sit at the fp32 noise
+    floor of the int64 oracle.  w=14 runs the 4-pass mode *inside* the
+    KMM2 window — the mode is valid anywhere in (m, 2m], not just on the
+    boundary."""
+    a, b = _operands(mkn, w, seed=w)
     oracle = ref_int_gemm_i64(np.asarray(a), np.asarray(b))
     for tiles in TILE_COMBOS:
-        bm, bn, bk = tiles
-        fused = ExecPlan("fused_mm2", w, backend="pallas", block_m=bm,
-                         block_n=bn, block_k=bk, depth=1)
-        staged = ExecPlan("mm2", w, backend="pallas", block_m=bm,
-                          block_n=bn, block_k=bk, depth=1)
-        out = np.asarray(ops.run_plan_jit(a, b, fused))
-        np.testing.assert_array_equal(
-            out, np.asarray(ops.run_plan_jit(a, b, staged)),
-            err_msg=f"fused_mm2 != staged mm2 at w={w} tiles={tiles}")
-        np.testing.assert_array_equal(
-            out, np.asarray(ops.run_plan_jit(a, b, fused,
-                                             use_ref_kernels=True)),
-            err_msg=f"fused_mm2 != jnp mirror at w={w} tiles={tiles}")
+        out, mirror = _kernel_and_mirror(a, b, w=w, mode="mm2", tiles=tiles,
+                                         combine_int32=False)
+        assert np.array_equal(out, mirror), \
+            f"fused mm2 != jnp mirror at w={w} tiles={tiles}"
         np.testing.assert_allclose(
             out.astype(np.float64), oracle, rtol=0,
             atol=_i64_oracle_atol(w, mkn[1]),
-            err_msg=f"fused_mm2 off the oracle at w={w} tiles={tiles}")
+            err_msg=f"fused mm2 off the oracle at w={w} tiles={tiles}")
 
 
 @pytest.mark.parametrize("w", [8, 12, 15, 20])
 @pytest.mark.parametrize("mkn", HOSTILE_SHAPES)
-def test_fused_depth2_bit_identical_to_staged_and_mirror(w, mkn):
+def test_fused_depth2_bit_identical_to_mirror(w, mkn):
     """Depth-2 fused recursion (9 MXU passes, nested Fig. 8 pre-adders in
-    VMEM): bit-identical to the staged two-level plane pipeline and the
-    jnp mirror, fp32-noise-close to the int64 oracle.  Depth 2 is forced
-    below its analytic window too (w=8/12/15) — the nested split must be
-    valid anywhere ``kmm_levels_needed(w, m) <= 2``."""
-    a, b = runner.make_operands(mkn, w, seed=w)
+    VMEM): bit-identical to the two-level jnp mirror, fp32-noise-close to
+    the int64 oracle.  Depth 2 is forced below its analytic window too
+    (w=8/12/15) — the nested split must be valid anywhere
+    ``kmm_levels_needed(w, m) <= 2``."""
+    a, b = _operands(mkn, w, seed=w)
     oracle = ref_int_gemm_i64(np.asarray(a), np.asarray(b))
     for tiles in TILE_COMBOS:
-        bm, bn, bk = tiles
-        fused = ExecPlan("fused", w, backend="pallas", block_m=bm,
-                         block_n=bn, block_k=bk, depth=2)
-        staged = ExecPlan("kmm2", w, backend="pallas", block_m=bm,
-                          block_n=bn, block_k=bk, depth=2)
-        out = np.asarray(ops.run_plan_jit(a, b, fused))
-        np.testing.assert_array_equal(
-            out, np.asarray(ops.run_plan_jit(a, b, staged)),
-            err_msg=f"fused d2 != staged d2 at w={w} tiles={tiles}")
-        np.testing.assert_array_equal(
-            out, np.asarray(ops.run_plan_jit(a, b, fused,
-                                             use_ref_kernels=True)),
-            err_msg=f"fused d2 != jnp mirror at w={w} tiles={tiles}")
+        out, mirror = _kernel_and_mirror(a, b, w=w, mode="kmm4", tiles=tiles,
+                                         combine_int32=False)
+        assert np.array_equal(out, mirror), \
+            f"fused d2 != jnp mirror at w={w} tiles={tiles}"
         np.testing.assert_allclose(
             out.astype(np.float64), oracle, rtol=0,
             atol=_i64_oracle_atol(w, mkn[1]),
             err_msg=f"fused d2 off the oracle at w={w} tiles={tiles}")
 
 
-@pytest.mark.parametrize("w", [4, 8, 12, 14, 15, 16, 20])
-def test_fused_pruned_space_candidates_pass_the_gate(w):
-    """Every fused plan the pruned tune space emits must pass the runner's
-    bit-exact correctness gate (the same gate the autotuner applies) —
-    including the fused_mm2 boundary mode (w=15, 16) and fused depth-2
-    (w=20)."""
-    shape = (16, 32, 16)
-    cands = [p for p in space.pruned_space(shape, w, backend="pallas",
-                                           tile_choices=(32, 64))
-             if p.variant in ("fused", "fused_mm2")]
-    assert cands, f"no fused candidates at w={w}"
-    if w in (15, 16):
-        assert any(p.variant == "fused_mm2" for p in cands)
-    if w == 20:
-        assert any(p.depth == 2 for p in cands)
-    a, b = runner.make_operands(shape, w, seed=w)
-    for plan in cands:
-        ok, err = runner.check_plan(plan, a, b)
-        assert ok, (plan, err)
-
-
 def test_fused_analytic_default_covers_windows():
-    """backend='pallas' analytic dispatch: fused for MM1 + KMM2 windows,
-    fused_mm2 on the (2m-2, 2m] boundary, fused depth-2 for 4-digit
-    recursion; only depth >= 3 stays staged."""
+    """The kernel's plan follows the paper's rule: mm1 (exact int32) for
+    the MM1 window, kmm2 for KMM2, mm2 on the (2m-2, 2m] boundary, kmm4
+    for 4-digit recursion; depth >= 3 is not the kernel's."""
+    shape = (16, 32, 16)
     for w in (4, 8):
-        plan = analytic_plan(w, backend="pallas")
-        assert plan.variant == "fused" and plan.is_exact_int
-    for w in (9, 12, 14):
-        plan = analytic_plan(w, backend="pallas")
-        assert plan.variant == "fused" and plan.depth == 1
-    for w in (15, 16):
-        plan = analytic_plan(w, backend="pallas")
-        assert plan.variant == "fused_mm2" and plan.depth == 1
-    for w in (17, 20, 26):
-        plan = analytic_plan(w, backend="pallas")
-        assert plan.variant == "fused" and plan.depth == 2
-    assert analytic_plan(28, backend="pallas").variant == "kmm2"
+        plan = plan_for(shape, w)
+        assert plan.mode == "mm1" and plan.combine_int32
+    for w, mode in ((9, "kmm2"), (12, "kmm2"), (14, "kmm2"), (15, "mm2"),
+                    (16, "mm2"), (17, "kmm4"), (20, "kmm4"), (26, "kmm4")):
+        plan = plan_for(shape, w)
+        assert plan.mode == mode and not plan.combine_int32, (w, plan)
+        exact = plan_for(shape, w, exact=True)
+        assert (exact is None) == (max_exact_k(w) < shape[1]), w
+        assert exact is None or exact.combine_int32
+    assert kernel_mode(28) is None and plan_for(shape, 28) is None
 
 
 # ---------------------------------------------------------------------------
-# Satellite: exact-int32 mode at the max_exact_k boundary.
+# Exact-int32 mode at the max_exact_k boundary.
 # ---------------------------------------------------------------------------
 
 
 def test_fused_exact_int32_at_max_exact_k_boundary():
     w = 12
     k = max_exact_k(w)                       # 128: the tight int32 ceiling
-    a, b = runner.make_operands((16, k, 16), w, seed=3)
-    plan = ExecPlan("fused", w, backend="pallas", block_m=32, block_n=32,
-                    block_k=32, combine_int32=True, depth=1)
-    assert space.validate(plan, (16, k, 16)) is None
-    out = np.asarray(ops.run_plan_jit(a, b, plan))
+    a, b = _operands((16, k, 16), w, seed=3)
+    assert plan_for((16, k, 16), w, exact=True) is not None
+    out = np.asarray(fused_gemm(a, b, w=w, block_m=32, block_n=32,
+                                block_k=32, combine_int32=True))
     assert out.dtype == np.int32
     np.testing.assert_array_equal(
         out.astype(np.int64),
         ref_int_gemm_i64(np.asarray(a), np.asarray(b)))
-    # one past the boundary: the pruner must reject the plan, and the
+    # one past the boundary: the plan must decline an exact run, and the
     # int_gemm API must refuse an exact request outright
-    assert space.validate(plan, (16, k + 1, 16)) is not None
+    assert plan_for((16, k + 1, 16), w, exact=True) is None
     with pytest.raises(ValueError, match="max exact K"):
         ops.int_gemm(jnp.zeros((16, k + 1), jnp.int32),
                      jnp.zeros((k + 1, 16), jnp.int32),
@@ -208,7 +176,7 @@ def test_fused_exact_int32_at_max_exact_k_boundary():
 
 
 # ---------------------------------------------------------------------------
-# Satellite: grouped expert grid vs a per-expert loop.
+# Grouped expert grid vs a per-expert loop.
 # ---------------------------------------------------------------------------
 
 
@@ -304,21 +272,24 @@ def test_quantized_batched_ragged_pallas_matches_xla():
 
 
 # ---------------------------------------------------------------------------
-# Satellite: dequant epilogue == staged dequant, exact fp32 equality.
+# Dequant epilogue == a separate dequant of the mirror, exact fp32 equality.
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("w", [8, 12])
 def test_dequant_epilogue_equals_staged_dequant(w):
+    """The in-kernel epilogue equals the jnp mirror's accumulator times
+    the scales as a separate step, bit for bit."""
     m, k, n = 17, 70, 9
-    a, b = runner.make_operands((m, k, n), w, seed=w)
+    a, b = _operands((m, k, n), w, seed=w)
     rng = np.random.default_rng(w)
     sx = jnp.asarray(rng.random((m, 1)), jnp.float32)
     sw = jnp.asarray(rng.random((1, n)), jnp.float32)
-    kw = dict(w=w, block_m=32, block_n=32, block_k=64)
-    fused = np.asarray(fused_gemm(a, b, sx, sw, **kw))
-    acc = np.asarray(fused_gemm(a, b, **kw)).astype(np.float32)
-    staged_dequant = acc * np.asarray(sx * sw)
+    fused = np.asarray(fused_gemm(a, b, sx, sw, w=w, block_m=32,
+                                  block_n=32, block_k=64))
+    acc = np.asarray(_mirror(a, b, w=w, mode="mm1" if w <= 8 else "kmm2",
+                             block_k=64, combine_int32=w <= 8))
+    staged_dequant = acc.astype(np.float32) * np.asarray(sx * sw)
     np.testing.assert_array_equal(fused, staged_dequant)
 
 
@@ -378,56 +349,8 @@ def test_pallas_route_falls_back_outside_fused_windows():
     np.testing.assert_array_equal(
         np.asarray(quantized_matmul(x, wm, 28)),
         np.asarray(quantized_matmul(x, wm, 28, 8, "auto", "pallas")))
-    assert select_plan((4, 32, 8), 28, backend="pallas").variant == "kmm2"
-    assert select_plan((4, 32, 8), 16, backend="pallas").variant \
-        == "fused_mm2"
-
-
-# ---------------------------------------------------------------------------
-# Dispatch/tuning seam: fused plans stay in the staged fingerprint class.
-# ---------------------------------------------------------------------------
-
-
-def test_table_can_swap_fused_and_staged_without_moving_bits():
-    """A tuning table recording a staged kmm2 winner is adopted over the
-    fused analytic default (same fp32 fingerprint class + same K padding)
-    and must not change a single output bit."""
-    from repro.tune.table import TuningTable, use_table
-
-    w, shape = 12, (64, 128, 64)
-    a, b = runner.make_operands(shape, w, seed=1)
-    base = np.asarray(ops.int_gemm(a, b, w=w, backend="pallas"))
-    t = TuningTable()
-    t.put("pallas", shape, w,
-          ExecPlan("kmm2", w, backend="pallas", block_m=32, block_n=32,
-                   block_k=256, combine_int32=False, depth=1))
-    with use_table(t):
-        plan = select_plan(shape, w, backend="pallas")
-        assert plan.variant == "kmm2" and plan.source == "table"
-        tabled = np.asarray(ops.int_gemm(a, b, w=w, backend="pallas"))
-    np.testing.assert_array_equal(base, tabled)
-
-
-def test_quantized_matmul_pallas_table_never_moves_bits():
-    """Numerics pinning holds on the pallas backend too: a table that
-    redirects the fused plan to a staged pallas plan (same fingerprint
-    class) must leave quantized_matmul(backend='pallas') bit-identical —
-    the redirect runs the staged kernel, never the XLA rounding class."""
-    from repro.tune.table import TuningTable, use_table
-
-    rng = np.random.default_rng(3)
-    x = jnp.asarray(rng.standard_normal((8, 256)), jnp.float32)
-    wm = jnp.asarray(rng.standard_normal((256, 64)), jnp.float32)
-    t = TuningTable()
-    t.put("pallas", (8, 256, 64), 12,
-          ExecPlan("kmm2", 12, backend="pallas", block_m=32, block_n=64,
-                   block_k=256, combine_int32=False, depth=1))
-    for w in (8, 12):
-        base = np.asarray(quantized_matmul(x, wm, w, 8, "auto", "pallas"))
-        with use_table(t):
-            tabled = np.asarray(quantized_matmul(x, wm, w, 8, "auto",
-                                                 "pallas"))
-        np.testing.assert_array_equal(base, tabled, err_msg=f"w={w}")
+    assert plan_for((4, 32, 8), 28) is None
+    assert plan_for((4, 32, 8), 16).mode == "mm2"
 
 
 def test_pallas_route_actually_runs_fused_at_serve_shapes():
@@ -439,11 +362,11 @@ def test_pallas_route_actually_runs_fused_at_serve_shapes():
     rng = np.random.default_rng(4)
     x = jnp.asarray(rng.standard_normal((2, 64)), jnp.float32)   # decode M=2
     wm = jnp.asarray(rng.standard_normal((64, 48)), jnp.float32)
-    from repro.quant.qmatmul import _quantize, _shrink_tiles
+    from repro.quant.qmatmul import _quantize
 
     qx, sx = _quantize(x, 12, axis=-1)
     qw, sw = _quantize(wm, 12, axis=0)
-    plan = _shrink_tiles(analytic_plan(12, backend="pallas"), (2, 64, 48))
+    plan = plan_for((2, 64, 48), 12)
     assert plan.tiles == (8, 64, 64)
     direct = np.asarray(fused_gemm(
         qx, qw, sx, sw, w=12, block_m=plan.block_m, block_n=plan.block_n,
@@ -603,30 +526,186 @@ TILE_SWEEP_SHAPES = [
 ]
 
 
+def _clamp_tiles(shape):
+    m_dim, k_dim, n_dim = shape
+    return (min(DEFAULT_TILES[0], pow2_cover(m_dim)),
+            min(DEFAULT_TILES[1], pow2_cover(n_dim)),
+            min(DEFAULT_TILES[2], pow2_cover(k_dim)))
+
+
 @pytest.mark.parametrize("w", [9, 12, 14, 16, 20, 24])
 def test_int8_tile_rule_keeps_padding_and_vmem(w):
-    """The int8 path's tiles (``int8_tiles``) pad K exactly as the analytic
-    256 clamp does, fit the VMEM budget, and leave table plans alone."""
-    from repro.core.context import ExecContext
-    from repro.quant.qmatmul import _fused_mode, _fused_plan_for, \
-        _shrink_tiles
-    from repro.tune.table import TuningTable
-
+    """The int8 path's tiles (``int8_tiles``) pad K exactly as the 256
+    clamp does and fit the VMEM budget; off the int8 path the plan keeps
+    the clamp.  Where the plan declines a deep-K shape, the zero-point
+    sums are why."""
+    mode = kernel_mode(w)
     for shape in TILE_SWEEP_SHAPES:
-        plan = _fused_plan_for(shape, w, 8, None)
-        clamp = _shrink_tiles(analytic_plan(w, backend="pallas"), shape)
-        tiles = int8_tiles(shape, _fused_mode(plan), w)
+        clamp = _clamp_tiles(shape)
+        tiles = int8_tiles(shape, mode, w)
         assert (tiles is None) == (w == 24), (w, shape)
-        assert plan.tiles == (tiles or clamp.tiles), (w, shape)
+        want = tiles or clamp
         k = shape[1]
-        assert -(-k // plan.block_k) * plan.block_k \
-            == -(-k // clamp.block_k) * clamp.block_k, (w, shape)
-        assert space.vmem_footprint(plan) <= space.VMEM_BUDGET, (w, shape)
-    shape = TILE_SWEEP_SHAPES[0]
-    base = analytic_plan(w, backend="pallas")
-    t = TuningTable()
-    t.put("pallas", shape, w,
-          replace(base, block_m=64, block_n=128, block_k=256))
-    plan = _fused_plan_for(shape, w, 8, ExecContext(backend="pallas",
-                                                    tuning_table=t))
-    assert plan.source.startswith("table") and plan.tiles == (64, 128, 256)
+        kp = -(-k // want[2]) * want[2]
+        assert kp == -(-k // clamp[2]) * clamp[2], (w, shape)
+        assert vmem_footprint(mode, w, *want) <= VMEM_BUDGET, (w, shape)
+        plan = plan_for(shape, w)
+        if plan is None:
+            assert not zero_point_sums_fit(w, k, kp), (w, shape)
+        else:
+            assert plan.tiles == want, (w, shape)
+
+
+# ---------------------------------------------------------------------------
+# Every width the kernel serves, at the plan plan_for picks.
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("w", range(2, 27))
+def test_fused_every_width_vs_reference(w):
+    """w 2-8 (mm1, exact by construction) equal the int64 oracle; w 9-26
+    (the fp32 combine the cells run: kmm2, mm2, kmm4) equal the jnp mirror
+    bit for bit and sit at the fp32 noise floor of the oracle.  K = 63 pads
+    to 64 and sits on w = 26's zero-point-sum edge."""
+    shape = (33, 63, 17)
+    plan = plan_for(shape, w)
+    assert plan is not None and plan.combine_int32 == (w <= 8)
+    a, b = _extreme_operands(w, *shape, seed=w)
+    oracle = ref_int_gemm_i64(np.asarray(a), np.asarray(b))
+    out, mirror = _kernel_and_mirror(a, b, w=w, mode=plan.mode,
+                                     tiles=plan.tiles,
+                                     combine_int32=plan.combine_int32)
+    if w <= 8:
+        np.testing.assert_array_equal(out.astype(np.int64), oracle)
+        return
+    assert np.array_equal(out, mirror), f"kernel != mirror at w={w} {plan}"
+    np.testing.assert_allclose(out.astype(np.float64), oracle, rtol=0,
+                               atol=_i64_oracle_atol(w, shape[1]),
+                               err_msg=f"kernel off the oracle at w={w}")
+
+
+def test_plan_declines_zero_point_sum_overflow():
+    """The int32 zero-point sums wrap once K * 2^(w-1) + Kp * z passes
+    2^31, which the fp32 combine cannot undo: on all-extreme rows the
+    kernel then misses the oracle by z * 2^32.  The plan declines there,
+    and the quantized pallas route falls back to XLA bit for bit."""
+    shape = (33, 70, 17)
+    assert plan_for((33, 63, 17), 26) is not None
+    assert plan_for((33, 64, 17), 26) is None and plan_for(shape, 26) is None
+    assert plan_for((8, 4088, 8), 20) is not None
+    assert plan_for((8, 4096, 8), 20) is None
+    a, b = _extreme_operands(26, *shape, seed=26)
+    wrapped = np.asarray(fused_gemm(a, b, w=26, mode="kmm4", block_m=64,
+                                    block_n=32, block_k=128))
+    oracle = ref_int_gemm_i64(np.asarray(a), np.asarray(b))
+    assert np.abs(wrapped - oracle).max() >= 2.0 ** 44
+    rng = np.random.default_rng(26)
+    x = jnp.asarray(rng.standard_normal((4, 70)), jnp.float32)
+    wm = jnp.asarray(rng.standard_normal((70, 8)), jnp.float32)
+    np.testing.assert_array_equal(
+        np.asarray(quantized_matmul(x, wm, 26)),
+        np.asarray(quantized_matmul(x, wm, 26, 8, "auto", "pallas")))
+
+
+@pytest.mark.parametrize("w", range(9, 15))
+def test_fused_exact_class_vs_oracle(w):
+    """The int32 combine at the exact window's edge (K = max_exact_k(w),
+    at most 200) equals the int64 oracle, extremes included."""
+    shape = (33, min(max_exact_k(w), 200), 17)
+    plan = plan_for(shape, w, exact=True)
+    assert plan is not None and plan.combine_int32
+    a, b = _extreme_operands(w, *shape, seed=w)
+    bm, bn, bk = plan.tiles
+    out = np.asarray(fused_gemm(a, b, w=w, mode=plan.mode, block_m=bm,
+                                block_n=bn, block_k=bk, combine_int32=True))
+    assert out.dtype == np.int32
+    np.testing.assert_array_equal(
+        out.astype(np.int64), ref_int_gemm_i64(np.asarray(a), np.asarray(b)))
+
+
+# ---------------------------------------------------------------------------
+# The plan at the shapes the benchmark serves: the tiles and combine the
+# fused route chose before it had its own plan function (the same programs).
+# ---------------------------------------------------------------------------
+
+# (K, N) of every quantized weight of the two benchmark configurations.
+SERVED_KN = [(5120, 5120), (5120, 1280), (5120, 13824), (13824, 5120),
+             (5120, 100352),                                   # stablelm-12b
+             (2560, 2560), (2560, 8960), (8960, 2560), (2560, 65536)]  # rwkv
+# (M, K, N) -> (block_m, block_n, block_k, combine_int32) at w = 12.
+SERVED_PLANS = {
+    (8, 5120, 5120): (8, 512, 512, False),
+    (32, 5120, 5120): (32, 512, 512, False),
+    (512, 5120, 5120): (512, 512, 512, False),
+    (4096, 5120, 5120): (512, 512, 512, False),
+    (8, 5120, 1280): (8, 256, 512, False),
+    (32, 5120, 1280): (32, 256, 512, False),
+    (512, 5120, 1280): (512, 256, 512, False),
+    (4096, 5120, 1280): (512, 256, 512, False),
+    (8, 5120, 13824): (8, 512, 512, False),
+    (32, 5120, 13824): (32, 512, 512, False),
+    (512, 5120, 13824): (512, 512, 512, False),
+    (4096, 5120, 13824): (512, 512, 512, False),
+    (8, 13824, 5120): (8, 512, 512, False),
+    (32, 13824, 5120): (32, 512, 512, False),
+    (512, 13824, 5120): (512, 512, 512, False),
+    (4096, 13824, 5120): (512, 512, 512, False),
+    (8, 5120, 100352): (8, 512, 512, False),
+    (32, 5120, 100352): (32, 512, 512, False),
+    (512, 5120, 100352): (512, 512, 512, False),
+    (4096, 5120, 100352): (512, 512, 512, False),
+    (8, 2560, 2560): (8, 512, 512, False),
+    (32, 2560, 2560): (32, 512, 512, False),
+    (512, 2560, 2560): (512, 512, 512, False),
+    (4096, 2560, 2560): (512, 512, 512, False),
+    (8, 2560, 8960): (8, 256, 512, False),
+    (32, 2560, 8960): (32, 256, 512, False),
+    (512, 2560, 8960): (512, 256, 512, False),
+    (4096, 2560, 8960): (512, 256, 512, False),
+    (8, 8960, 2560): (8, 512, 256, False),
+    (32, 8960, 2560): (32, 512, 256, False),
+    (512, 8960, 2560): (512, 512, 256, False),
+    (4096, 8960, 2560): (512, 512, 256, False),
+    (8, 2560, 65536): (8, 512, 512, False),
+    (32, 2560, 65536): (32, 512, 512, False),
+    (512, 2560, 65536): (512, 512, 512, False),
+    (4096, 2560, 65536): (512, 512, 512, False),
+}
+
+
+@pytest.mark.parametrize("shape", list(SERVED_PLANS), ids=str)
+def test_plan_at_served_shapes(shape):
+    """Decode (M 8, 32) and prefill (M 512, 4096) widths of every served
+    GEMM: the plan picks the kmm2 mode with the recorded tiles and the fp32
+    combine.  Runs no kernel."""
+    plan = plan_for(shape, 12)
+    assert plan.mode == "kmm2"
+    assert (*plan.tiles, plan.combine_int32) == SERVED_PLANS[shape]
+
+
+def test_served_shapes_cover_the_benchmark_models():
+    """SERVED_KN is every (K, N) that the benchmark's configurations
+    quantize, read from the models' own parameter trees."""
+    from repro.models import lm
+    from repro.quant.prequant import _QUANT_LEAVES
+
+    bench = Path(__file__).resolve().parents[1] / "bench"
+    spec = importlib.util.spec_from_file_location("bench_run_for_shapes",
+                                                  bench / "run.py")
+    run = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = run            # dataclasses look it up
+    try:
+        spec.loader.exec_module(run)
+    finally:
+        del sys.modules[spec.name]
+    assert {m for (m, _, _) in SERVED_PLANS} == {8, 32, 512, 4096}
+    assert {(k, n) for (_, k, n) in SERVED_PLANS} == set(SERVED_KN)
+    found = set()
+    for cfg_file in sorted((bench / "configs").glob("*.json")):
+        pcfg = run.program_config(json.loads(cfg_file.read_text()))
+        tree = jax.eval_shape(
+            lambda: lm.init_params(jax.random.PRNGKey(0), pcfg))
+        for path, leaf in jax.tree_util.tree_leaves_with_path(tree):
+            if str(getattr(path[-1], "key", "")) in _QUANT_LEAVES:
+                found.add(tuple(leaf.shape[-2:]))
+    assert found == set(SERVED_KN), sorted(found ^ set(SERVED_KN))

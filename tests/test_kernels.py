@@ -6,14 +6,12 @@ import jax
 import jax.numpy as jnp
 from _hypothesis_compat import given, settings, strategies as st
 
-from repro.kernels.ops import int_gemm, quantize_symmetric
+from repro.kernels.fused_gemm import fused_gemm
+from repro.kernels.ops import int_gemm
 from repro.kernels.ref import (
-    ref_digit_planes, ref_int_gemm_i64, ref_kmm2_planes, ref_mm2_planes,
+    ref_digit_planes, ref_fused_gemm, ref_int_gemm_i64,
 )
-from repro.kernels.kmm_gemm import kmm2_gemm_planes
-from repro.kernels.mm2_gemm import mm2_gemm_planes
-from repro.kernels.mm1_gemm import mm1_gemm
-from repro.kernels.ffip import ffip_gemm_literal, ffip_mults
+from repro.quant.quantize import quantize_symmetric
 
 
 def _rand_w(rng, w, shape):
@@ -104,27 +102,25 @@ def test_property_kmm2_kernel_tiling_invariance(w, bm, seed):
     rng = np.random.default_rng(seed)
     a = _rand_w(rng, w, (64, 128))
     b = _rand_w(rng, w, (128, 64))
-    h = -(-w // 2)
-    from repro.kernels.ops import _planes
-    a1, a0, _ = _planes(jnp.array(a), h)
-    b1, b0, _ = _planes(jnp.array(b), h)
-    ref = np.asarray(ref_kmm2_planes(a1, a0, b1, b0, h))
-    out = np.asarray(kmm2_gemm_planes(a1, a0, b1, b0, h=h, block_m=bm,
-                                      block_n=32, block_k=32))
+    ref = np.asarray(ref_fused_gemm(jnp.array(a), jnp.array(b), w=w,
+                                    mode="kmm2", block_k=32,
+                                    combine_int32=False))
+    out = np.asarray(fused_gemm(jnp.array(a), jnp.array(b), w=w,
+                                mode="kmm2", block_m=bm, block_n=32,
+                                block_k=32))
     np.testing.assert_allclose(out, ref, rtol=1e-6)
 
 
 def test_mm2_kernel_vs_planes_oracle():
     rng = np.random.default_rng(0)
-    w, h = 16, 8
+    w = 16
     a = _rand_w(rng, w, (64, 128))
     b = _rand_w(rng, w, (128, 64))
-    from repro.kernels.ops import _planes
-    a1, a0, _ = _planes(jnp.array(a), h)
-    b1, b0, _ = _planes(jnp.array(b), h)
-    out = np.asarray(mm2_gemm_planes(a1, a0, b1, b0, h=h, block_m=32,
-                                     block_n=32, block_k=64))
-    ref = np.asarray(ref_mm2_planes(a1, a0, b1, b0, h))
+    out = np.asarray(fused_gemm(jnp.array(a), jnp.array(b), w=w, mode="mm2",
+                                block_m=32, block_n=32, block_k=64))
+    ref = np.asarray(ref_fused_gemm(jnp.array(a), jnp.array(b), w=w,
+                                    mode="mm2", block_k=64,
+                                    combine_int32=False))
     np.testing.assert_allclose(out, ref, rtol=1e-6)
 
 
@@ -132,24 +128,9 @@ def test_mm1_kernel_exact():
     rng = np.random.default_rng(1)
     a = rng.integers(-128, 128, size=(128, 256)).astype(np.int8)
     b = rng.integers(-128, 128, size=(256, 128)).astype(np.int8)
-    out = np.asarray(mm1_gemm(jnp.array(a), jnp.array(b), block_m=64,
-                              block_n=64, block_k=64))
+    out = np.asarray(fused_gemm(jnp.array(a), jnp.array(b), w=8, mode="mm1",
+                                block_m=64, block_n=64, block_k=64))
     np.testing.assert_array_equal(out.astype(np.int64), ref_int_gemm_i64(a, b))
-
-
-class TestFFIP:
-    def test_literal_matches_matmul(self):
-        rng = np.random.default_rng(2)
-        a = rng.integers(-64, 64, size=(10, 24)).astype(np.int32)
-        b = rng.integers(-64, 64, size=(24, 8)).astype(np.int32)
-        out = np.asarray(ffip_gemm_literal(jnp.array(a), jnp.array(b)))
-        np.testing.assert_array_equal(out.astype(np.int64),
-                                      ref_int_gemm_i64(a, b))
-
-    def test_halves_multiplications(self):
-        m, k, n = 64, 128, 64
-        conv = m * n * k
-        assert ffip_mults(m, k, n) / conv == pytest.approx(0.5, abs=0.05)
 
 
 def test_quantize_symmetric_roundtrip():

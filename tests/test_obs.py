@@ -1,6 +1,6 @@
-"""Observability-layer tests (repro.obs: metrics / trace / traffic).
+"""Observability-layer tests (repro.obs: metrics / trace).
 
-Pins the three contracts DESIGN.md §14 promises:
+Pins the two contracts DESIGN.md §14 promises:
 
   * metrics registry semantics — counter/gauge/histogram math, idempotent
     registration, deterministic snapshots, thread-safety under concurrent
@@ -9,9 +9,6 @@ Pins the three contracts DESIGN.md §14 promises:
     schema of the export, async begin/end pairing, the disabled path
     writing no buffer event and reading no clock of its own, and the serve
     engine's spans on the profiler's timeline;
-  * traffic harness — cost_analysis bytes/flops validated against a
-    hand-computed plain matmul, and the measured-vs-analytic rows/checks on
-    a tiny shape;
 
 plus the acceptance bar: serve tokens are bit-identical with observability
 fully enabled vs fully disabled, and steady-state decode shows zero
@@ -24,7 +21,7 @@ import numpy as np
 import pytest
 import jax
 
-from repro.obs import metrics, trace, traffic
+from repro.obs import metrics, trace
 
 
 @pytest.fixture()
@@ -217,76 +214,6 @@ def test_export_chrome(obs_on, tmp_path):
     trace.export_chrome(str(out))
     doc = json.loads(out.read_text())
     assert [e["name"] for e in doc["traceEvents"]] == ["one"]
-
-
-# ---------------------------------------------------------------------------
-# Traffic harness
-# ---------------------------------------------------------------------------
-
-
-def test_measure_costs_known_matmul():
-    """cost_analysis bytes/flops against a hand-computed f32 matmul:
-    (64,64)@(64,64) reads two operands, writes one output (3*64*64*4
-    bytes) and does 2*64^3 flops."""
-    import jax.numpy as jnp
-
-    spec = jax.ShapeDtypeStruct((64, 64), jnp.float32)
-    got = traffic.measure_costs(jax.jit(lambda a, b: a @ b)
-                                .lower(spec, spec))
-    assert got["method"] in ("cost_analysis", "hlo_text")
-    assert got["flops"] == pytest.approx(2 * 64 ** 3)
-    assert got["bytes"] == pytest.approx(3 * 64 * 64 * 4, rel=0.05)
-    # the analytic xla model is exactly this floor
-    assert traffic.analytic_bytes("xla", (64, 64, 64)) == 3 * 64 * 64 * 4
-
-
-def test_analytic_bytes_models():
-    shape, tiles = (64, 256, 64), (64, 64, 64)
-    fused = traffic.analytic_bytes("fused", shape, w=12, tiles=tiles)
-    staged = traffic.analytic_bytes("staged", shape, w=12, tiles=tiles)
-    xla = traffic.analytic_bytes("xla", shape)
-    # the paper's claim, in the model itself: fused < staged, both real
-    assert 0 < fused < staged
-    assert xla == 4 * (64 * 256 + 256 * 64) + 4 * 64 * 64
-    # w<=m drops the fused operand carrier to s8 (half the plane reads)
-    assert traffic.analytic_bytes("fused", shape, w=8, tiles=tiles) < fused
-    with pytest.raises(ValueError):
-        traffic.analytic_bytes("nope", shape, tiles=tiles)
-
-
-def test_traffic_rows_and_checks_smoke():
-    shapes = traffic.SMOKE_SHAPES[:1]
-    rows = traffic.traffic_rows(shapes, w=traffic.DEFAULT_W)
-    measured = [r for r in rows if "measured_bytes" in r]
-    assert {r["kind"] for r in measured} == set(traffic.TRAFFIC_KINDS)
-    assert all(r["measured_bytes"] > 0 for r in measured)
-    assert all(r["analytic_bytes"] > 0 for r in measured)
-    ratio_rows = [r for r in rows if "bytes_ratio" in r]
-    assert len(ratio_rows) == 1
-    checks = traffic.traffic_checks(rows)
-    failed = [c for c in checks if not c[1]]
-    assert not failed, failed
-    # the committed claim on this shape: fused moves fewer bytes
-    assert ratio_rows[0]["bytes_ratio"] < 1.0
-
-
-def test_measure_plan_bytes_swallows_failure():
-    class Bogus:                      # not an ExecPlan: lower() must fail
-        pass
-    assert traffic.measure_plan_bytes(Bogus(), None, None) == 0.0
-
-
-def test_tune_runner_records_bytes():
-    from repro.tune import runner
-
-    res = runner.tune_shape((32, 64, 32), 8, backend="pallas", iters=1,
-                            tile_choices=(32,), max_candidates=2)
-    ok = [m for m in res.measurements if m.ok]
-    assert ok and all(m.bytes > 0 for m in ok)
-    off = runner.tune_shape((32, 64, 32), 8, backend="pallas", iters=1,
-                            tile_choices=(32,), max_candidates=1,
-                            record_bytes=False)
-    assert all(m.bytes == 0.0 for m in off.measurements)
 
 
 # ---------------------------------------------------------------------------

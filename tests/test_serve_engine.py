@@ -306,49 +306,6 @@ def test_arrival_trace_queues_admission(tiny):
         assert rs.first_token_s >= rs.arrival_s
 
 
-def test_tuning_table_changes_no_tokens(tmp_path):
-    """Serving with a repro.tune table installed (Engine(tuning_table=...))
-    is token-identical to serving without one: the registry only retunes
-    how quantized GEMMs execute, never what they compute."""
-    from repro.core.dispatch import ExecPlan
-    from repro.tune import TuningTable, get_active_table, set_active_table
-
-    qcfg = get_config("llama3.2-1b", smoke=True, quant="w8").scaled_down(
-        d_model=64, d_ff=128, vocab_size=256, n_heads=4, n_kv_heads=2,
-        head_dim=16)
-    qparams = lm.init_params(jax.random.PRNGKey(7), qcfg)
-    spec = [(3, 3, 0.0, ()), (6, 2, 0.8, ())]
-
-    def run(table_path):
-        eng = Engine(qcfg, qparams, max_seq=32, batch_size=2, rng_seed=1,
-                     tuning_table=table_path)
-        reqs = _mk_requests(qcfg, spec)
-        eng.generate(reqs)
-        return [r.generated for r in reqs]
-
-    try:
-        base = run(None)
-        # one entry targeting the decode GEMM bucket + prior fallback for
-        # every other key (both paths must preserve numerics)
-        t = TuningTable(device="test")
-        t.put("xla", (2, 64, 64), 8,
-              ExecPlan("mm2", 8, backend="xla", depth=1,
-                       combine_int32=False))
-        path = tmp_path / "serve_table.json"
-        t.save(path)
-        tuned = run(str(path))
-        assert get_active_table() is not None
-        assert base == tuned
-    finally:
-        set_active_table(None)
-
-
-# ---------------------------------------------------------------------------
-# PR-5 acceptance: backend="pallas" serves through the fused kernel with
-# token-identical output vs the XLA backend (default, non-tabled dispatch).
-# ---------------------------------------------------------------------------
-
-
 def test_serve_quant_backend_pallas_token_identical(tiny):
     cfg, params = tiny
     spec = [(5, 4, 0.0, ()), (9, 3, 0.8, ()), (3, 4, 0.0, ())]

@@ -5,9 +5,7 @@ Covers:
   * capability negotiation unit behaviour (axes picked / reasons given);
   * kernel-level bit-identity: the fused KMM2 kernel shard-mapped over a
     2x4 mesh == the unsharded fused kernel, bit-for-bit (fp32 w12 class
-    AND exact w8 class vs the int64 oracle);
-  * K-sharded exact-int split: int32 partials psum'd over the model axis
-    == the oracle, and the fp32 class refuses K-sharding;
+    AND exact w8 class, whose integer accumulator is the int64 oracle's);
   * engine token-identity: quantized serve with backend="pallas" on the
     2x4 mesh == the same engine unsharded (and the XLA backend for w8);
   * capability-negotiation fallback: a (1, 8) mesh with a d_ff the model
@@ -31,9 +29,8 @@ import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.context import ExecContext
-from repro.core.dispatch import GemmShardSpec, select_plan
 from repro.dist import shard_gemm as sg
-from repro.kernels import ops
+from repro.dist.shard_gemm import GemmShardSpec
 from repro.kernels.ref import ref_int_gemm_i64
 from repro.launch.mesh import make_mesh
 from repro.quant.qmatmul import quantized_matmul, quantized_matmul_batched
@@ -71,32 +68,23 @@ assert np.array_equal(np.asarray(sharded), np.asarray(unsharded)), \
     "sharded fused w12 != unsharded (fp32 class must be bit-exact)"
 
 # ---- kernel-level bit-identity: exact w8 class vs int64 oracle ------------
-a8 = jnp.asarray(rng.integers(-120, 120, (M, K)), jnp.int32)
-b8 = jnp.asarray(rng.integers(-120, 120, (K, N)), jnp.int32)
-plan8 = select_plan((M, K, N), 8, backend="pallas")
+# Quantized operands with unit scales: the dequant epilogue multiplies the
+# int32 accumulator by 1.0, so the output is the integer product itself.
+a8 = jnp.asarray(rng.integers(-127, 128, (M, K)), jnp.float32)
+b8 = jnp.asarray(rng.integers(-127, 128, (K, N)), jnp.float32)
+a8 = a8.at[:, 0].set(127.0)                 # per-row abs-max 127 -> scale 1
+b8 = b8.at[0, :].set(127.0)                 # per-col abs-max 127 -> scale 1
+unsharded8 = quantized_matmul(a8, b8, 8, context=ExecContext(backend="pallas"))
 with jax.set_mesh(mesh):
-    out8 = sg.sharded_run_plan(a8, b8, plan=plan8, mesh=mesh)
+    out8 = quantized_matmul(
+        jax.device_put(a8, NamedSharding(mesh, P("data", None))),
+        jax.device_put(b8, NamedSharding(mesh, P(None, "model"))), 8,
+        context=ExecContext(backend="pallas", mesh=mesh))
 oracle = ref_int_gemm_i64(np.asarray(a8), np.asarray(b8))
+assert np.array_equal(np.asarray(out8), np.asarray(unsharded8)), \
+    "M/N-sharded exact w8 != unsharded"
 assert np.array_equal(np.asarray(out8).astype(np.int64), oracle), \
     "M/N-sharded exact w8 != int64 oracle"
-
-# ---- K-sharded exact-int split (psum of int32 partials) -------------------
-kspec = GemmShardSpec(m_axes=("data",), k_axes=("model",))
-from dataclasses import replace
-with jax.set_mesh(mesh):
-    outk = sg.sharded_run_plan(a8, b8, plan=replace(plan8, shard=kspec),
-                               mesh=mesh)
-assert np.array_equal(np.asarray(outk).astype(np.int64), oracle), \
-    "K-sharded exact w8 != int64 oracle"
-plan12 = select_plan((M, K, N), 12, backend="pallas")
-if not plan12.is_exact_int:
-    try:
-        with jax.set_mesh(mesh):
-            sg.sharded_run_plan(a8, b8, plan=replace(plan12, shard=kspec),
-                                mesh=mesh)
-        raise AssertionError("fp32-combine plan accepted K-sharding")
-    except ValueError as e:
-        assert "exact-int" in str(e)
 
 # ---- grouped expert GEMM under the mesh -----------------------------------
 E, C = 8, 8

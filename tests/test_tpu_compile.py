@@ -14,8 +14,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.fused_gemm import (dot_path, fused_gemm,
-                                     fused_gemm_grouped, int8_tiles)
-from repro.quant.qmatmul import _fused_mode, _fused_plan_for
+                                     fused_gemm_grouped, int8_tiles,
+                                     plan_for)
 
 # llama3.2-1b serve GEMMs: decode (4 slots) FFN up-projection, and a
 # 512-token prefill of the attention output projection.
@@ -49,15 +49,15 @@ def _compiled_text(fn, args, sharding) -> str:
 @pytest.mark.parametrize("shape", [DECODE, PREFILL], ids=["decode", "prefill"])
 @pytest.mark.parametrize("w", [8, 12, 16, 20])
 def test_fused_serve_plan_compiles_for_v5e(one_chip, w, shape):
-    """The plan ``_fused_plan_for`` picks, with the dequant epilogue the
+    """The plan ``plan_for`` picks, with the dequant epilogue the
     quantized matmul threads in: mm1 (w8), kmm2 (w12), mm2 (w16) and kmm4
     (w20)."""
-    plan = _fused_plan_for(shape, w, 8, None)
-    assert plan is not None and plan.variant in ("fused", "fused_mm2")
+    plan = plan_for(shape, w)
+    assert plan is not None
     m, k, n = shape
 
     def gemm(a, b, sx, sw):
-        return fused_gemm(a, b, sx, sw, w=w, mode=_fused_mode(plan),
+        return fused_gemm(a, b, sx, sw, w=w, mode=plan.mode,
                           block_m=plan.block_m, block_n=plan.block_n,
                           block_k=plan.block_k,
                           combine_int32=plan.combine_int32,
@@ -73,7 +73,7 @@ def test_fused_serve_plan_compiles_for_v5e(one_chip, w, shape):
 def test_int8_tiles_compile_for_v5e(one_chip, shape):
     """The int8 digit-dot path at the tiles its rule picks fits v5e's scoped
     VMEM (up to (512, 512, 512); (512, 1024, 512) overflows it)."""
-    plan = _fused_plan_for(shape, 12, 8, None)
+    plan = plan_for(shape, 12)
     assert plan.tiles == int8_tiles(shape, "kmm2", 12)
     assert dot_path("kmm2", 12, plan.block_k, interpret=False) == "int8"
     m, k, n = shape
@@ -94,7 +94,7 @@ def test_ragged_grouped_compiles_for_v5e(one_chip):
     d_ff_expert 512) on the ragged grouped kernel at w12: per-expert live
     counts are read as scalars from SMEM."""
     e, c, k, n, seg = 40, 64, 1536, 512, 16
-    plan = _fused_plan_for((c, k, n), 12, 8, None)
+    plan = plan_for((c, k, n), 12)
 
     def gemm(a, b, sx, sw, counts):
         return fused_gemm_grouped(
